@@ -11,7 +11,6 @@ from jax.sharding import PartitionSpec as P
 
 from benchmarks.common import emit, mesh_dp
 from repro.core import monitor_fn
-from repro.compat import shard_map
 
 
 def main():
@@ -26,7 +25,7 @@ def main():
                              [(i, (i + 1) % 8) for i in range(8)])
         return a.sum() + b.sum() + c.sum() + d.sum()
 
-    prog = shard_map(program, mesh=mesh, in_specs=P("data"),
+    prog = jax.shard_map(program, mesh=mesh, in_specs=P("data"),
                          out_specs=P(), check_vma=False)
     rep = monitor_fn(prog, jax.ShapeDtypeStruct((64, 256), jnp.float32),
                      mesh=mesh, name="Fig3")

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from benchmarks.common import ARTIFACTS, emit
-from repro.compat import make_mesh, shard_map
+from repro.compat import make_mesh
 from repro.core import monitor_fn
 from repro.core.reporter import format_table, human_bytes
 
@@ -36,9 +36,9 @@ def _program(mesh):
         g = jax.lax.psum(x, tuple(mesh.axis_names))
         return (x * g).sum()
 
-    return shard_map(step, mesh=mesh,
-                     in_specs=P(mesh.axis_names[0]),
-                     out_specs=P(), check_vma=False)
+    return jax.shard_map(step, mesh=mesh,
+                         in_specs=P(mesh.axis_names[0]),
+                         out_specs=P(), check_vma=False)
 
 
 def main():

@@ -17,7 +17,6 @@ from benchmarks.common import emit, mesh_dp
 from repro.core import (hlo_parser, parse_hlo_collectives,
                         table1_allreduce_bytes, wire_bytes_per_rank)
 from repro.core.reporter import format_table, human_bytes
-from repro.compat import shard_map
 
 
 def measured_payload(kind: str, n: int, elems: int) -> float:
@@ -35,7 +34,7 @@ def measured_payload(kind: str, n: int, elems: int) -> float:
         return jax.lax.all_to_all(x, "data", split_axis=0, concat_axis=0,
                                   tiled=True)
 
-    g = jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"),
+    g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"),
                               out_specs=P("data"), check_vma=False))
     # global shape chosen so the collective's logical payload S is exactly
     # elems*4 bytes per group in every case

@@ -18,7 +18,6 @@ from repro.core.events import HostTransfer
 from repro.data import SyntheticSeq2Seq, host_transfer_log
 from repro.models.gnmt import GNMT
 from repro.train import ddp
-from repro.compat import shard_map
 
 
 def build(mesh):
@@ -50,7 +49,7 @@ def training_program(model, mesh):
         metrics = jax.lax.all_gather(losses, "data")
         return params, metrics
 
-    return shard_map(epoch, mesh=mesh,
+    return jax.shard_map(epoch, mesh=mesh,
                          in_specs=(P(), P(None, "data")),
                          out_specs=(P(), P()), check_vma=False)
 

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import make_mesh, shard_map
+from repro.compat import make_mesh
 from repro.core import MonitorSession
 from repro.core.reporter import (ascii_heatmap, lint_table,
                                  primitive_usage_table)
@@ -50,9 +50,9 @@ def build_program(mesh):
         return jax.lax.all_to_all(h.reshape(n, CAP, D),
                                   "data", 0, 0)                # combine
 
-    return shard_map(step, mesh=mesh,
-                     in_specs=(P("data"), P(), P()),
-                     out_specs=P("data"), check_vma=False)
+    return jax.shard_map(step, mesh=mesh,
+                         in_specs=(P("data"), P(), P()),
+                         out_specs=P("data"), check_vma=False)
 
 
 def hot_expert_transform(op):

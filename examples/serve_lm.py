@@ -19,14 +19,13 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax
-import jax.numpy as jnp
 
 from repro import configs
-from repro.core import MonitorSession
+from repro.compat import make_mesh
+from repro.launch.serve import monitor
 from repro.models import build_model
 from repro.parallel import Sharder
-from repro.serve import ServeConfig, cache_shardings, generate
-from repro.compat import make_mesh
+from repro.serve import ServeConfig, generate, make_serve_steps
 
 
 def main():
@@ -47,37 +46,22 @@ def main():
     prompts = jax.random.randint(jax.random.PRNGKey(1),
                                  (args.batch, args.prompt_len), 0,
                                  cfg.vocab_size)
+    max_len = args.prompt_len + args.tokens
+    steps = make_serve_steps(model, shd, ServeConfig(max_len=max_len,
+                                                     batch=args.batch),
+                             params_sh)
     t0 = time.perf_counter()
-    out = generate(model, params, prompts, shd, steps=args.tokens,
-                   max_len=args.prompt_len + args.tokens)
+    out, _ = generate(model, params, prompts, shd, steps=args.tokens,
+                      max_len=max_len, serve_steps=steps)
     dt = time.perf_counter() - t0
     print(f"served {args.batch} requests x {args.tokens} tokens in {dt:.1f}s "
           f"({args.batch*args.tokens/dt:.1f} tok/s incl. compile)")
     print("sample completion ids:", out[0, :12].tolist())
 
-    # prefill/decode communication profile: one two-phase session over
-    # ShapeDtypeStruct stand-ins (no allocation, nothing executes)
-    max_len = args.prompt_len + args.tokens
-    scfg = ServeConfig(max_len=max_len, batch=args.batch)
-    cache_sh = cache_shardings(model, scfg, shd)
-    cache_shapes = model.cache_shapes(args.batch, max_len)
-    sess = MonitorSession(mesh=mesh, name=f"serve[{cfg.name}]")
-    with sess:
-        with sess.phase("prefill"):
-            sess.capture(
-                lambda p, b: model.prefill(p, b, shd, max_len=max_len),
-                model.shapes(),
-                {"tokens": jax.ShapeDtypeStruct(
-                    (args.batch, args.prompt_len), jnp.int32)},
-                name="prefill", out_shardings=(None, cache_sh))
-        with sess.phase("decode"):
-            sess.capture(
-                lambda p, c, b: model.decode_step(p, c, b, shd),
-                model.shapes(), cache_shapes,
-                {"tokens": jax.ShapeDtypeStruct((args.batch, 1),
-                                                jnp.int32)},
-                name="decode", in_shardings=(None, cache_sh, None),
-                out_shardings=(None, cache_sh))
+    # prefill/decode communication profile of the served steps: one
+    # two-phase session over ShapeDtypeStruct stand-ins (nothing executes)
+    sess = monitor(model, steps, mesh, args.batch, args.prompt_len,
+                   name=f"serve[{cfg.name}]")
     rep = sess.report()
     print()
     print(rep.phase_table())

@@ -31,7 +31,7 @@ from repro.data import SyntheticSeq2Seq
 from repro.models.gnmt import GNMT
 from repro.optim import OptConfig, apply_updates, init_opt_state
 from repro.train import ddp
-from repro.compat import make_mesh, shard_map
+from repro.compat import make_mesh
 
 
 def main():
@@ -57,7 +57,7 @@ def main():
         params, opt, _ = apply_updates(params, grads, opt, ocfg, i)
         return params, opt, loss
 
-    sharded_step = jax.jit(shard_map(
+    sharded_step = jax.jit(jax.shard_map(
         step, mesh=mesh, in_specs=(P(), P(), P(), P("data")),
         out_specs=(P(), P(), P()), check_vma=False))
 
@@ -88,8 +88,8 @@ def main():
         return params, opt
 
     def dp(fn, in_specs, out_specs):
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     batch = data.batch_at(0)
     grads_like = params                    # same pytree shapes as the grads

@@ -1,4 +1,4 @@
-from repro.cli import main
+from repro.cli import entry
 
 if __name__ == "__main__":
-    raise SystemExit(main())
+    raise SystemExit(entry())

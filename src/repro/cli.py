@@ -621,5 +621,14 @@ def main(argv=None) -> int:
         return 2
 
 
+def entry() -> int:
+    """Process entry point (``python -m repro``, the ``repro`` script):
+    :func:`main` with jax's persistent compilation cache on.  In-process
+    callers of :func:`main` keep their own jax configuration."""
+    from repro.compat import enable_compile_cache
+    enable_compile_cache()
+    return main()
+
+
 if __name__ == "__main__":
-    raise SystemExit(main())
+    raise SystemExit(entry())

@@ -1,9 +1,6 @@
-"""Version tolerance for the handful of jax APIs that moved across releases.
-
-The repo targets current jax, but must degrade gracefully on the oldest
-toolchain we support (0.4.x): ``jax.sharding.AxisType`` and ``jax.shard_map``
-only exist on newer versions, so every call site goes through these wrappers
-instead of feature-detecting locally.
+"""Process-level jax setup shared by the CLI, the launchers and the scripts:
+forced host-device counts for CPU modelling, Auto-typed meshes, and the
+persistent compilation cache.
 """
 from __future__ import annotations
 
@@ -11,13 +8,19 @@ import os
 
 import jax
 
+# the checkout root: src/repro/compat.py -> <checkout>
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+CHECKOUT_CACHE_DIR = os.path.join(_CHECKOUT, ".jax_cache")
+
 
 def ensure_host_devices(n: int):
     """Append ``--xla_force_host_platform_device_count=n`` to ``XLA_FLAGS``.
 
     Appends rather than overwrites so user-set flags survive; an existing
     device-count flag (user-chosen) wins.  Must run before the jax backend
-    initializes (first device query).
+    initializes (first device query).  Only the CPU backend reads it: on a
+    host with an accelerator the default backend keeps its own devices.
     """
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
@@ -25,63 +28,21 @@ def ensure_host_devices(n: int):
             flags + f" --xla_force_host_platform_device_count={n}").strip()
 
 
-def mesh_axis_types_kw(n_axes: int) -> dict:
-    """``{"axis_types": (Auto,)*n}`` where the jax API supports it, else {}."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache; returns its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` wins when set; otherwise the cache
+    lives at the fixed ``<checkout>/.jax_cache``.  The path is part of what
+    a later run must find again, so it is never a temporary or per-process
+    directory.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or CHECKOUT_CACHE_DIR
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
+    """``jax.make_mesh`` with Auto axis types (jax defaults to Explicit)."""
     return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                         **mesh_axis_types_kw(len(axis_names)))
-
-
-_HAS_COMBINER: bool | None = None
-
-
-def has_allreduce_combiner() -> bool:
-    """Does this jaxlib's compiler combine independent all-reduces?
-
-    XLA's all-reduce combiner pass performs DDP-style gradient bucketing
-    automatically (paper Table 3's optimization, done by the compiler).
-    Old CPU jaxlibs (0.4.x) never run it, so per-parameter psums stay
-    1-per-tensor in the compiled module.  This probes the actual behavior
-    -- compile a two-psum program and count the surviving all-reduce ops --
-    rather than guessing from version strings.  The result is cached for
-    the process (one small compile, first call only).
-    """
-    global _HAS_COMBINER
-    if _HAS_COMBINER is not None:
-        return _HAS_COMBINER
-
-    from jax.sharding import PartitionSpec as P
-
-    mesh = make_mesh((jax.device_count(),), ("_probe",))
-
-    def two_psums(a, b):
-        return (jax.lax.psum(a, "_probe"), jax.lax.psum(b, "_probe"))
-
-    fn = jax.jit(shard_map(two_psums, mesh=mesh,
-                           in_specs=(P("_probe"), P("_probe")),
-                           out_specs=(P("_probe"), P("_probe"))))
-    import jax.numpy as jnp
-    args = [jax.ShapeDtypeStruct((jax.device_count(), 8), jnp.float32)] * 2
-    hlo = fn.lower(*args).compile().as_text()
-    n_ar = len([l for l in hlo.splitlines()
-                if " all-reduce(" in l or " all-reduce-start(" in l])
-    _HAS_COMBINER = n_ar <= 1
-    return _HAS_COMBINER
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map``, falling back to the pre-promotion experimental API
-    (where ``check_vma`` was spelled ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))

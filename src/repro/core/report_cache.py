@@ -7,7 +7,7 @@ So the sweep engine caches whole :class:`~repro.core.monitor.CommReport`
 objects on disk, serialized through :mod:`repro.core.export.serialize`.
 
 **Cache-key semantics.**  A key is the SHA-256 (first 20 hex chars) of the
-JSON tuple ``(schema, config, mesh, algorithm, jax_version)``:
+JSON tuple ``(schema, config, mesh, algorithm, jax_version, device)``:
 
 * ``config``  -- the sweep config identity *including its builder version
   string* (e.g. ``"gnmt/v1:d=64,layers=2,steps=4"``), so editing a builder
@@ -20,7 +20,11 @@ JSON tuple ``(schema, config, mesh, algorithm, jax_version)``:
   ``CommReport.view(algorithm)`` binding, snapshotted by
   ``CommReport.rebound``);
 * ``jax_version`` -- XLA's collective emission changes across releases, so
-  reports never survive a jax upgrade.
+  reports never survive a jax upgrade;
+* ``device``  -- the default backend's platform and ``device_kind`` (e.g.
+  ``"cpu:cpu"``, ``"tpu:TPU v5 lite"``): the TPU compiler emits a different
+  schedule than the CPU one, so a report compiled on forced host devices is
+  never served as a chip's (the cache directory travels with the checkout).
 
 **Phase-aware entries.**  Sessions capture under named phases, but a phase
 is a *view* of the session snapshot, not a separate compilation -- so a
@@ -51,10 +55,19 @@ _SCHEMA = "repro.report_cache.v1"
 DEFAULT_ROOT = os.path.join("artifacts", "report_cache")
 
 
+def device_id() -> str:
+    """``"<platform>:<device_kind>"`` of the default backend's devices."""
+    import jax
+    dev = jax.devices()[0]
+    return f"{dev.platform}:{dev.device_kind}"
+
+
 def cache_key(config: str, mesh: str, algorithm: str,
               jax_version: Optional[str] = None, *,
-              phase: Optional[str] = None) -> str:
-    """Deterministic key for one (config, mesh, algorithm, jax) cell.
+              phase: Optional[str] = None,
+              device: Optional[str] = None) -> str:
+    """Deterministic key for one (config, mesh, algorithm, jax, device)
+    cell; ``jax_version`` and ``device`` default to this process's.
 
     ``phase`` is accepted -- and deliberately **not hashed** -- so a
     per-phase sweep cell addresses the whole-session snapshot it derives
@@ -66,7 +79,10 @@ def cache_key(config: str, mesh: str, algorithm: str,
     if jax_version is None:
         import jax
         jax_version = jax.__version__
-    blob = json.dumps([_SCHEMA, config, mesh, algorithm, jax_version])
+    if device is None:
+        device = device_id()
+    blob = json.dumps([_SCHEMA, config, mesh, algorithm, jax_version,
+                       device])
     return hashlib.sha256(blob.encode()).hexdigest()[:20]
 
 

@@ -122,7 +122,7 @@ def analyze(
     topo: MeshTopology,
     hw: HardwareSpec = V5E,
     model_flops: float = 0.0,
-    memory_stats: Optional[dict] = None,
+    memory_stats: dict,
     algorithm: str = "ring",
     link_utilization=None,
 ) -> RooflineReport:
@@ -168,7 +168,7 @@ def analyze(
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     dominant = max(terms, key=terms.get)
     total_flops = flops * topo.num_devices
-    mem = dict(memory_stats or {})
+    mem = dict(memory_stats)
     mem["cost_analysis_flops"] = float(cost.get("flops", 0.0))
     mem["cost_analysis_bytes"] = float(cost.get("bytes accessed", 0.0))
     mem["hlo_bytes_logical"] = bytes_logical

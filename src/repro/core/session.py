@@ -52,30 +52,29 @@ from .views import CommView, build_view
 DEFAULT_PHASE = "main"
 
 
-def _memory_stats(compiled) -> Optional[dict]:
-    try:
-        m = compiled.memory_analysis()
-        return {
-            "argument_bytes": m.argument_size_in_bytes,
-            "output_bytes": m.output_size_in_bytes,
-            "temp_bytes": m.temp_size_in_bytes,
-            "alias_bytes": m.alias_size_in_bytes,
-            "generated_code_bytes": m.generated_code_size_in_bytes,
-            "total_bytes": (m.argument_size_in_bytes + m.output_size_in_bytes
-                            + m.temp_size_in_bytes - m.alias_size_in_bytes),
-        }
-    except Exception:
-        return None
+def _memory_stats(compiled) -> dict:
+    """The compiler's per-device memory analysis.  A backend that cannot
+    give one fails the capture: a report without it has no memory stats
+    and no roofline, which must not pass for a finished capture."""
+    m = compiled.memory_analysis()
+    return {
+        "argument_bytes": m.argument_size_in_bytes,
+        "output_bytes": m.output_size_in_bytes,
+        "temp_bytes": m.temp_size_in_bytes,
+        "alias_bytes": m.alias_size_in_bytes,
+        "generated_code_bytes": m.generated_code_size_in_bytes,
+        "total_bytes": (m.argument_size_in_bytes + m.output_size_in_bytes
+                        + m.temp_size_in_bytes - m.alias_size_in_bytes),
+    }
 
 
 def _cost_analysis(compiled) -> dict:
-    try:
-        c = compiled.cost_analysis()
-        if isinstance(c, (list, tuple)):
-            c = c[0] if c else {}
-        return dict(c)
-    except Exception:
-        return {}
+    """The compiler's cost analysis (FLOPs, bytes accessed); raises where
+    the backend gives none, for the same reason as :func:`_memory_stats`."""
+    cost = compiled.cost_analysis()
+    if not cost:
+        raise RuntimeError("the compiler returned no cost analysis")
+    return dict(cost)
 
 
 @dataclasses.dataclass
@@ -94,7 +93,7 @@ class Capture:
     trace_seconds: float
     compile_seconds: float
     cost: dict
-    memory_stats: Optional[dict]
+    memory_stats: dict
     hlo_text: str = ""
 
 
@@ -181,7 +180,9 @@ class MonitorSession:
 
         ``args``/``kwargs`` may be concrete arrays or
         ``jax.ShapeDtypeStruct`` stand-ins (nothing executes; no device
-        memory is allocated).  The parsed ops and traced events are tagged
+        memory is allocated).  A ``jax.jit``-wrapped ``fn`` given no jit
+        options here is captured as it is, so the report describes the
+        program the caller runs.  The parsed ops and traced events are tagged
         with ``phase`` (default: the innermost active :meth:`phase`, else
         ``"main"``) and accumulated into the session.
 
@@ -204,7 +205,14 @@ class MonitorSession:
             jit_kw["donate_argnums"] = donate_argnums
         if static_argnums:
             jit_kw["static_argnums"] = static_argnums
-        jitted = jax.jit(fn, **jit_kw)
+        # an already-jitted fn with no options of its own is lowered as it
+        # is: the capture is of the very object the caller runs
+        jitted = (fn if not jit_kw and hasattr(fn, "lower")
+                  else jax.jit(fn, **jit_kw))
+        if jitted is fn:
+            # a trace cached by an earlier call would hide its collectives
+            # from the interceptor; the next call of fn compiles again
+            fn.clear_cache()
 
         t0 = time.perf_counter()
         with CollectiveInterceptor(mesh=self.mesh) as icpt:
@@ -308,20 +316,17 @@ class MonitorSession:
             return dict(self.captures[0].cost)
         out: dict[str, float] = {}
         for cap in self.captures:
-            for k, v in (cap.cost or {}).items():
+            for k, v in cap.cost.items():
                 if isinstance(v, (int, float)):
                     out[k] = out.get(k, 0.0) + float(v)
         return out
 
-    def _merged_memory_stats(self) -> Optional[dict]:
+    def _merged_memory_stats(self) -> dict:
         if len(self.captures) == 1:
             return self.captures[0].memory_stats
-        stats = [c.memory_stats for c in self.captures if c.memory_stats]
-        if not stats:
-            return None
         out: dict[str, float] = {}
-        for st in stats:
-            for k, v in st.items():
+        for cap in self.captures:
+            for k, v in cap.memory_stats.items():
                 if isinstance(v, (int, float)):
                     out[k] = out.get(k, 0) + v
         return out

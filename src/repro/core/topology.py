@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from typing import Optional
 
 
 @dataclasses.dataclass(frozen=True)
@@ -37,6 +38,30 @@ class HardwareSpec:
 
 
 V5E = HardwareSpec()
+
+# Hardware spec per TPU ``device_kind`` as jax reports it.  V5E's peak
+# FLOP/s and HBM size and bandwidth are Google Cloud's published TPU v5e
+# figures ("TPU v5e" system architecture page); its ICI/DCN link and hop
+# figures are datasheet-level constants, not fitted on a chip (ROADMAP B8).
+HW_BY_DEVICE_KIND = {"TPU v5 lite": V5E}
+
+
+def hardware_of(mesh) -> HardwareSpec:
+    """The :class:`HardwareSpec` of a mesh's devices.
+
+    The forced CPU host devices of dry-run modelling model a v5e, the
+    stated target.  Every other platform takes the spec of its
+    ``device_kind``; a kind without one is an error, never a silent
+    default.
+    """
+    dev = mesh.devices.flat[0]
+    if dev.platform == "cpu":
+        return V5E
+    if dev.device_kind not in HW_BY_DEVICE_KIND:
+        raise ValueError(
+            f"no HardwareSpec for {dev.platform} device kind "
+            f"{dev.device_kind!r}; known kinds: {sorted(HW_BY_DEVICE_KIND)}")
+    return HW_BY_DEVICE_KIND[dev.device_kind]
 
 # sentinel device id for the inter-pod DCN fabric endpoint of a link
 DCN_FABRIC = -1
@@ -83,11 +108,14 @@ class MeshTopology:
     dcn_axes: tuple[str, ...] = ("pod",)
 
     @classmethod
-    def from_mesh(cls, mesh, hw: HardwareSpec = V5E, dcn_axes=("pod",)):
+    def from_mesh(cls, mesh, hw: Optional[HardwareSpec] = None,
+                  dcn_axes=("pod",)):
+        """Topology of a jax mesh; ``hw`` defaults to
+        :func:`hardware_of` the mesh's devices."""
         return cls(
             axis_names=tuple(mesh.axis_names),
             axis_sizes=tuple(mesh.devices.shape),
-            hw=hw,
+            hw=hw or hardware_of(mesh),
             dcn_axes=tuple(dcn_axes),
         )
 

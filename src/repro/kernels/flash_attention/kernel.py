@@ -23,11 +23,11 @@ skipped with ``pl.when`` (the MXU never sees them).  Block sizes default to
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -100,13 +100,52 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 512,
                     q_offset: int = 0, interpret: bool = False):
-    """q: (B,Sq,H,dh); k,v: (B,Skv,KVH,dh) -> (B,Sq,H,dh)."""
+    """q: (B,Sq,H,dh); k,v: (B,Skv,KVH,dh) -> (B,Sq,H,dh).
+
+    Differentiable: the backward pass is the VJP of the chunked XLA path
+    (:func:`repro.models.attention.chunked_attention`, the kernel's oracle),
+    recomputed from q, k and v -- the kernel itself is forward-only.
+    """
+    block_q = min(block_q, q.shape[1])
+    block_k = min(block_k, k.shape[1])
+    return _flash_attention(q, k, v, causal, window, block_q, block_k,
+                            q_offset, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_attention(q, k, v, causal, window, block_q, block_k, q_offset,
+                     interpret):
+    return _pallas_forward(q, k, v, causal, window, block_q, block_k,
+                           q_offset, interpret)
+
+
+def _flash_attention_fwd(q, k, v, causal, window, block_q, block_k,
+                         q_offset, interpret):
+    out = _pallas_forward(q, k, v, causal, window, block_q, block_k,
+                          q_offset, interpret)
+    return out, (q, k, v)
+
+
+def _flash_attention_bwd(causal, window, block_q, block_k, q_offset,
+                         interpret, residuals, g):
+    from repro.models.attention import chunked_attention
+    _, vjp = jax.vjp(functools.partial(
+        chunked_attention, causal=causal, window=window, q_chunk=block_q,
+        q_offset=q_offset), *residuals)
+    return vjp(g)
+
+
+_flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
+
+
+def _pallas_forward(q, k, v, causal, window, block_q, block_k, q_offset,
+                    interpret):
     b, sq, h, dh = q.shape
     _, skv, kvh, _ = k.shape
     g = h // kvh
-    block_q = min(block_q, sq)
-    block_k = min(block_k, skv)
-    assert sq % block_q == 0 and skv % block_k == 0, (sq, skv, block_q, block_k)
+    if sq % block_q or skv % block_k:
+        raise ValueError(f"block sizes ({block_q}, {block_k}) must divide "
+                         f"the sequence lengths ({sq}, {skv})")
     nq, nk = sq // block_q, skv // block_k
     scale = dh ** -0.5
 
@@ -131,22 +170,13 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                                lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, dh), q.dtype),
         scratch_shapes=[
-            pl_scratch((block_q, dh)),   # acc
-            pl_scratch((block_q,)),      # m (running max)
-            pl_scratch((block_q,)),      # l (running denom)
+            pltpu.VMEM((block_q, dh), jnp.float32),   # acc
+            pltpu.VMEM((block_q,), jnp.float32),      # m (running max)
+            pltpu.VMEM((block_q,), jnp.float32),      # l (running denom)
         ],
         interpret=interpret,
     )(qt, kt, vt)
     return out.swapaxes(1, 2)
-
-
-def pl_scratch(shape):
-    """fp32 VMEM scratch accumulator."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM(shape, jnp.float32)
-    except Exception:  # CPU interpret fallback
-        return pl.MemorySpace.ANY(shape, jnp.float32)  # pragma: no cover
 
 
 def vmem_bytes(block_q: int, block_k: int, dh: int, dtype_bytes: int = 2) -> int:

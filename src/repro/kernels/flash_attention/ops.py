@@ -7,18 +7,7 @@ the choice of backend never changes numerics beyond dtype-accumulation noise
 """
 from __future__ import annotations
 
-import functools
-import os
-
 import jax
-import jax.numpy as jnp
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 def attend(q, k, v, *, causal: bool = True, window: int = 0,
@@ -27,7 +16,7 @@ def attend(q, k, v, *, causal: bool = True, window: int = 0,
 
     ``force``: "" (auto) | "pallas" | "pallas_interpret" | "xla" | "ref".
     """
-    backend = force or ("pallas" if _on_tpu() else "xla")
+    backend = force or ("pallas" if jax.default_backend() == "tpu" else "xla")
     if backend in ("pallas", "pallas_interpret"):
         from .kernel import flash_attention
         sq, skv = q.shape[1], k.shape[1]

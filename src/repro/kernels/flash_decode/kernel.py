@@ -21,6 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -101,18 +102,11 @@ def flash_decode(q, k_cache, v_cache, cache_len, *, window: int = 0,
         out_specs=pl.BlockSpec((1, 1, dh), lambda b_, h_, ki: (b_, h_, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, dh), q.dtype),
         scratch_shapes=[
-            _scratch((dh,)),     # acc
-            _scratch((1,)),      # m
-            _scratch((1,)),      # l
+            pltpu.VMEM((dh,), jnp.float32),     # acc
+            pltpu.VMEM((1,), jnp.float32),      # m
+            pltpu.VMEM((1,), jnp.float32),      # l
         ],
         interpret=interpret,
     )(cache_len, qt.reshape(b, h, dh), kt, vt)
     return out
 
-
-def _scratch(shape):
-    from jax.experimental.pallas import tpu as pltpu
-    try:
-        return pltpu.VMEM(shape, jnp.float32)
-    except Exception:  # pragma: no cover
-        return None

@@ -5,17 +5,10 @@ from __future__ import annotations
 import jax
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
 def decode_attend(q, k_cache, v_cache, cache_len, *, window: int = 0,
                   force: str = ""):
     """q: (B,H,dh); k/v: (B,L,KVH,dh) -> (B,H,dh)."""
-    backend = force or ("pallas" if _on_tpu() else "xla")
+    backend = force or ("pallas" if jax.default_backend() == "tpu" else "xla")
     if backend in ("pallas", "pallas_interpret"):
         from .kernel import flash_decode
         lmax = k_cache.shape[1]

@@ -12,16 +12,9 @@ import jax
 import jax.numpy as jnp
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
 def rglru_scan(x, log_a, h0=None, *, force: str = "", seq_chunk: int = 4096):
     """h_t = exp(log_a_t)*h_{t-1} + x_t over axis 1.  (B,S,D) -> (B,S,D)."""
-    backend = force or ("pallas" if _on_tpu() else "xla")
+    backend = force or ("pallas" if jax.default_backend() == "tpu" else "xla")
     if backend in ("pallas", "pallas_interpret"):
         from .kernel import rglru_pallas
         b, s, d = x.shape

@@ -4,15 +4,8 @@ from __future__ import annotations
 import jax
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
 def rmsnorm(x, w, eps: float = 1e-6, *, force: str = ""):
-    backend = force or ("pallas" if _on_tpu() else "xla")
+    backend = force or ("pallas" if jax.default_backend() == "tpu" else "xla")
     if backend in ("pallas", "pallas_interpret"):
         from .kernel import rmsnorm_pallas
         return rmsnorm_pallas(x, w, eps=eps,

@@ -25,10 +25,9 @@ def make_production_mesh(*, multi_pod: bool = False):
             "set XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import")
     # slice explicitly: a 512-device process also builds the 256-chip mesh
-    from jax.sharding import Mesh
-    from repro.compat import mesh_axis_types_kw
+    from jax.sharding import AxisType, Mesh
     return Mesh(np.array(devs[:n]).reshape(shape), axes,
-                **mesh_axis_types_kw(len(axes)))
+                axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(shape=(4, 2), axes=("data", "model")):

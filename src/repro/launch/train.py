@@ -1,7 +1,8 @@
 """End-to-end training driver.
 
-Runs a REAL training loop (reduced arch configs on CPU; the same code path
-scales to the production meshes) with: deterministic data, checkpoint/resume
+Runs a REAL training loop (reduced arch configs by default, the published
+ones with ``--published``; the same code path scales to the production
+meshes) with: deterministic data, checkpoint/resume
 (fault tolerance), async checkpointing, and a communication report from the
 monitor at the end — the paper's workflow folded into the trainer.
 
@@ -19,6 +20,7 @@ import numpy as np
 
 from repro import configs
 from repro.checkpoint import AsyncCheckpointer, latest_step, restore_checkpoint
+from repro.compat import enable_compile_cache
 from repro.core import monitor_fn
 from repro.data import SyntheticLMData, host_transfer_log
 from repro.launch.mesh import make_test_mesh
@@ -33,6 +35,8 @@ from repro.train.train import (batch_shardings, make_train_step,
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite_3_2b")
+    ap.add_argument("--published", action="store_true",
+                    help="the published config (default: the reduced one)")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--seq-len", type=int, default=64)
@@ -50,7 +54,7 @@ def main(argv=None):
     mesh = make_test_mesh(shape, ("data", "model")[:len(shape)])
     shd = Sharder(mesh)
 
-    cfg = configs.config(args.arch, reduced=True)
+    cfg = configs.config(args.arch, reduced=not args.published)
     model = build_model(cfg)
     ocfg = OptConfig(peak_lr=args.lr, warmup_steps=10,
                      decay_steps=max(100, args.steps))
@@ -115,4 +119,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -32,15 +32,21 @@ def cache_shardings(model, serve_cfg: ServeConfig, shd: Sharder):
     return shd.tree_shardings(shapes, axes)
 
 
+def token_sharding(shd: Sharder, batch: int):
+    """Where a ``(batch, n)`` token array lives: batch over the data axes."""
+    return shd.named((batch, 1), ("batch", None))
+
+
 def make_decode_step(model, shd: Sharder, serve_cfg: ServeConfig,
-                     params_sh=None, donate_cache: bool = True):
+                     params_sh=None, donate_cache: bool = True,
+                     batch_sh=None):
     """jit'd decode_step(params, cache, batch) -> (logits, cache)."""
     cache_sh = cache_shardings(model, serve_cfg, shd)
 
     def step(params, cache, batch):
         return model.decode_step(params, cache, batch, shd)
 
-    kw = dict(in_shardings=(params_sh, cache_sh, None),
+    kw = dict(in_shardings=(params_sh, cache_sh, batch_sh),
               out_shardings=(None, cache_sh))
     if donate_cache:
         kw["donate_argnums"] = (1,)
@@ -48,25 +54,46 @@ def make_decode_step(model, shd: Sharder, serve_cfg: ServeConfig,
 
 
 def make_prefill_step(model, shd: Sharder, serve_cfg: ServeConfig,
-                      params_sh=None):
+                      params_sh=None, batch_sh=None):
     cache_sh = cache_shardings(model, serve_cfg, shd)
 
     def step(params, batch):
         return model.prefill(params, batch, shd, max_len=serve_cfg.max_len)
 
-    return jax.jit(step, in_shardings=(params_sh, None),
+    return jax.jit(step, in_shardings=(params_sh, batch_sh),
                    out_shardings=(None, cache_sh)), cache_sh
 
 
+def make_serve_steps(model, shd: Sharder, serve_cfg: ServeConfig,
+                     params_sh=None):
+    """The jitted ``(prefill, decode)`` pair :func:`generate` runs; a
+    monitor that captures these same objects reports the served program.
+    Every input's sharding is pinned, so shape stand-ins lower to the
+    program the live arguments run."""
+    batch_sh = {"tokens": token_sharding(shd, serve_cfg.batch)}
+    prefill, _ = make_prefill_step(model, shd, serve_cfg, params_sh, batch_sh)
+    decode, _ = make_decode_step(model, shd, serve_cfg, params_sh,
+                                 donate_cache=False, batch_sh=batch_sh)
+    return prefill, decode
+
+
 def generate(model, params, prompts, shd: Sharder, *, steps: int = 16,
-             max_len: int = 256, rng=None, temperature: float = 0.0):
-    """Greedy/temperature batched generation (examples + integration tests)."""
+             max_len: int = 256, rng=None, temperature: float = 0.0,
+             serve_steps=None):
+    """Greedy/temperature batched generation (launcher, examples, tests).
+
+    ``serve_steps`` is a ``(prefill, decode)`` pair from
+    :func:`make_serve_steps` (default: built here for ``max_len``).
+    Returns ``(tokens, logits)``: the ``(B, steps)`` generated ids and the
+    ``(B, steps, vocab)`` fp32 logits each was sampled from.
+    """
     scfg = ServeConfig(max_len=max_len, batch=prompts.shape[0],
                        temperature=temperature)
-    prefill, _ = make_prefill_step(model, shd, scfg)
-    decode, _ = make_decode_step(model, shd, scfg, donate_cache=False)
-    logits, cache = prefill(params, {"tokens": prompts})
-    toks = []
+    prefill, decode = serve_steps or make_serve_steps(model, shd, scfg)
+    tok_sh = token_sharding(shd, scfg.batch)
+    logits, cache = prefill(params,
+                            {"tokens": jax.device_put(prompts, tok_sh)})
+    logits = logits.astype(jnp.float32)
     rng = rng if rng is not None else jax.random.PRNGKey(0)
 
     def sample(logits, rng):
@@ -74,11 +101,14 @@ def generate(model, params, prompts, shd: Sharder, *, steps: int = 16,
             return jax.random.categorical(rng, logits / temperature, axis=-1)
         return jnp.argmax(logits, axis=-1)
 
-    tok = sample(logits.astype(jnp.float32), rng)
-    toks.append(tok)
+    tok = sample(logits, rng)
+    toks, all_logits = [tok], [logits]
     for i in range(steps - 1):
         rng, k = jax.random.split(rng)
-        logits, cache = decode(params, cache, {"tokens": tok[:, None]})
-        tok = sample(logits[:, -1].astype(jnp.float32), k)
+        logits, cache = decode(
+            params, cache, {"tokens": jax.device_put(tok[:, None], tok_sh)})
+        logits = logits[:, -1].astype(jnp.float32)
+        tok = sample(logits, k)
         toks.append(tok)
-    return jnp.stack(toks, axis=1)
+        all_logits.append(logits)
+    return jnp.stack(toks, axis=1), jnp.stack(all_logits, axis=1)

@@ -67,6 +67,24 @@ def mesh_id(spec: str) -> str:
     return "x".join(map(str, shape)) + ":" + ",".join(axes)
 
 
+def check_mesh_fits(spec: str):
+    """Raise ``ValueError`` when ``spec`` needs more devices than the
+    default backend has.  The backend is never swapped for forced CPU
+    devices behind the user's back: on a chip host a larger mesh is
+    modelled by running under ``JAX_PLATFORMS=cpu``."""
+    import math
+
+    import jax
+    shape, _ = parse_mesh(spec)
+    need, have = math.prod(shape), jax.device_count()
+    if need > have:
+        dev = jax.devices()[0]
+        raise ValueError(
+            f"mesh {spec} needs {need} devices but this host has {have} "
+            f"{dev.platform} device(s) ({dev.device_kind}); CPU modelling "
+            f"of larger meshes runs under JAX_PLATFORMS=cpu")
+
+
 def build_mesh(spec: str):
     from repro.compat import make_mesh
     shape, axes = parse_mesh(spec)
@@ -150,7 +168,6 @@ def _build_gnmt(mesh):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.models.gnmt import GNMT
     from repro.train import ddp
 
@@ -176,9 +193,9 @@ def _build_gnmt(mesh):
         metrics = jax.lax.all_gather(losses, "data")
         return params, metrics
 
-    prog = shard_map(epoch, mesh=mesh,
-                     in_specs=(P(), P(None, "data")),
-                     out_specs=(P(), P()), check_vma=False)
+    prog = jax.shard_map(epoch, mesh=mesh,
+                         in_specs=(P(), P(None, "data")),
+                         out_specs=(P(), P()), check_vma=False)
     i32 = jnp.int32
     batches = {k: jax.ShapeDtypeStruct((steps, b, seq), i32)
                for k in ("src", "tgt", "labels")}
@@ -273,7 +290,6 @@ def _build_moe_skew(mesh):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.models.common import ModelConfig
     from repro.models.moe import group_capacity
 
@@ -292,9 +308,9 @@ def _build_moe_skew(mesh):
         back = jax.lax.all_to_all(h.reshape(n, cap, d), "data", 0, 0)
         return back                                               # combine
 
-    prog = shard_map(step, mesh=mesh,
-                     in_specs=(P("data"), P(), P()),
-                     out_specs=P("data"), check_vma=False)
+    prog = jax.shard_map(step, mesh=mesh,
+                         in_specs=(P("data"), P(), P()),
+                         out_specs=P("data"), check_vma=False)
     f32 = jnp.float32
     args = (jax.ShapeDtypeStruct((n * n, cap, d), f32),
             jax.ShapeDtypeStruct((d, f), f32),
@@ -559,6 +575,8 @@ def run_sweep(
             f"unknown config(s) {unknown}; known: {sorted(registry)}")
     for alg in algorithms:
         validate_algorithm(alg)
+    for mspec in mesh_specs:
+        check_mesh_fits(mspec)
     cache = cache or ReportCache()
     result = SweepResult(reports=[], failures=[], cache_hits=0, compiles=0)
     jobs = resolve_jobs(jobs)

@@ -119,9 +119,8 @@ def make_ddp_train_step(loss_fn: Callable, mesh, *, axis_name: str = "data",
 
     in_specs = (P(), P(), P(axis_name))
     out_specs = (P(), P(), P())
-    from repro.compat import shard_map
-    mapped = shard_map(step, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
+    mapped = jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     return jax.jit(mapped)
 
 

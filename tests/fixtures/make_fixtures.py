@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import make_mesh, shard_map
+from repro.compat import make_mesh
 from repro.core import MonitorSession
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -72,8 +72,8 @@ def translation_report():
         return params, opt
 
     def dp(fn, in_specs, out_specs):
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     batch = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
                          data.batch_at(0))

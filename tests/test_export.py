@@ -440,6 +440,16 @@ class TestCache:
         assert cache_key("a/v1", "4x2:data,model", "ring",
                          jax_version="2") != base
 
+    def test_key_separates_cpu_from_tpu(self):
+        """A report compiled on forced host devices is never a chip's."""
+        from repro.core.report_cache import device_id
+        assert device_id() == "cpu:cpu"
+        cpu = cache_key("a/v1", "1:data", "ring", jax_version="1")
+        assert cpu == cache_key("a/v1", "1:data", "ring", jax_version="1",
+                                device="cpu:cpu")
+        assert cpu != cache_key("a/v1", "1:data", "ring", jax_version="1",
+                                device="tpu:TPU v5 lite")
+
     def test_put_get_roundtrip(self, tmp_path):
         cache = ReportCache(root=str(tmp_path / "cache"))
         key = cache_key("golden/v1", "4:data", "ring")

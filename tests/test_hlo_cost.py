@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.hlo_cost import (HloAnalyzer, analyze_hlo,
                                  computation_multipliers, split_computations,
                                  top_ops)
@@ -44,7 +43,7 @@ class TestMultipliers:
             c, _ = jax.lax.scan(body, x, None, length=16)
             return c
 
-        gg = jax.jit(shard_map(g, mesh=mesh_dp, in_specs=P("data"),
+        gg = jax.jit(jax.shard_map(g, mesh=mesh_dp, in_specs=P("data"),
                                    out_specs=P("data"), check_vma=False))
         hlo = gg.lower(jax.ShapeDtypeStruct((8, 64), jnp.float32)) \
             .compile().as_text()

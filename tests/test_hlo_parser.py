@@ -7,7 +7,6 @@ from jax.sharding import PartitionSpec as P
 from repro.core import hlo_parser
 from repro.core.hlo_parser import (HLOParseError, parse_hlo_collectives,
                                    parse_replica_groups)
-from repro.compat import shard_map
 
 
 class TestSyntheticLines:
@@ -129,7 +128,7 @@ class TestRealModule:
             z = jax.lax.all_gather(y, "model")
             return z.sum()
 
-        g = jax.jit(shard_map(f, mesh=mesh8, in_specs=P("data"),
+        g = jax.jit(jax.shard_map(f, mesh=mesh8, in_specs=P("data"),
                                   out_specs=P(), check_vma=False))
         hlo = g.lower(jnp.ones((8, 16))).compile().as_text()
         ops = parse_hlo_collectives(hlo)

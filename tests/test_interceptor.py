@@ -4,7 +4,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core import CollectiveInterceptor, intercept
-from repro.compat import shard_map
 
 import pytest
 
@@ -18,7 +17,7 @@ def _traced_program(mesh):
         w = jax.lax.ppermute(x, "data", [(i, (i + 1) % 4) for i in range(4)])
         return y.sum() + z.sum() + w.sum()
 
-    return jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"),
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"),
                                  out_specs=P(), check_vma=False))
 
 
@@ -63,7 +62,7 @@ class TestInterceptor:
         prog = _traced_program(mesh8)
         expected = prog(x)
         with intercept(mesh8):
-            got = jax.jit(shard_map(
+            got = jax.jit(jax.shard_map(
                 lambda v: jax.lax.psum(v, "data").sum(), mesh=mesh8,
                 in_specs=P("data"), out_specs=P(), check_vma=False))(x)
         assert jnp.isfinite(got)

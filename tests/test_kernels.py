@@ -61,6 +61,35 @@ class TestFlashAttention:
                              interpret=True)
         assert jnp.max(jnp.abs(o1 - o2)) < 2e-5
 
+    @pytest.mark.parametrize("sq,h,kvh,window,q_offset", [
+        (256, 4, 2, 0, 0),       # GQA causal, two q blocks
+        (256, 4, 1, 64, 0),      # MQA + sliding window
+        (128, 2, 2, 0, 32),      # prefill continuation offset
+    ])
+    def test_gradient_matches_xla_path(self, sq, h, kvh, window, q_offset):
+        """The kernel's custom VJP equals the XLA chunked path's gradient,
+        for q, k and v (GQA: k/v grads summed over each query group)."""
+        from repro.models.attention import chunked_attention
+        ks = jax.random.split(jax.random.PRNGKey(sq + h + window), 4)
+        q = rand(ks[0], (2, sq, h, 32))
+        k = rand(ks[1], (2, sq, kvh, 32))
+        v = rand(ks[2], (2, sq, kvh, 32))
+        w = rand(ks[3], (2, sq, h, 32))
+
+        def loss(attn):
+            return lambda q, k, v: jnp.sum(attn(q, k, v) * w)
+
+        kernel = loss(lambda q, k, v: flash_attention(
+            q, k, v, window=window, q_offset=q_offset, block_q=128,
+            block_k=128, interpret=True))
+        xla = loss(lambda q, k, v: chunked_attention(
+            q, k, v, window=window, q_offset=q_offset, q_chunk=64))
+        got = jax.grad(kernel, argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(xla, argnums=(0, 1, 2))(q, k, v)
+        for g, r in zip(got, want):
+            assert g.shape == r.shape
+            assert jnp.max(jnp.abs(g - r)) < 1e-4
+
     def test_xla_chunked_path_matches(self):
         from repro.models.attention import chunked_attention
         ks = jax.random.split(RNG, 3)

@@ -197,6 +197,38 @@ def phased_session(mesh8):
     return sess
 
 
+class TestHardwareOfMesh:
+    """A session's topology takes the chip's spec from its device_kind."""
+
+    @staticmethod
+    def _mesh(platform, kind):
+        import types
+        dev = types.SimpleNamespace(platform=platform, device_kind=kind)
+        return types.SimpleNamespace(devices=np.array([dev, dev]),
+                                     axis_names=("data",))
+
+    def test_v5e_kind_maps_to_v5e(self):
+        from repro.core import MeshTopology, V5E
+        topo = MeshTopology.from_mesh(self._mesh("tpu", "TPU v5 lite"))
+        assert topo.hw is V5E and topo.axis_sizes == (2,)
+
+    def test_unknown_tpu_kind_is_an_error(self):
+        from repro.core import MeshTopology
+        with pytest.raises(ValueError, match="TPU v9"):
+            MeshTopology.from_mesh(self._mesh("tpu", "TPU v9"))
+
+    def test_gpu_mesh_without_a_spec_is_an_error(self):
+        """Only CPU dry-run meshes model a v5e; a GPU is never given TPU
+        link and peak constants."""
+        from repro.core import MeshTopology
+        with pytest.raises(ValueError, match="gpu device kind 'NVIDIA H100'"):
+            MeshTopology.from_mesh(self._mesh("gpu", "NVIDIA H100"))
+
+    def test_cpu_mesh_models_v5e(self, mesh8):
+        from repro.core import MeshTopology, V5E
+        assert MeshTopology.from_mesh(mesh8).hw is V5E
+
+
 @pytest.mark.compile
 class TestMonitorSession:
     def test_phase_order_and_records(self, phased_session):
@@ -205,6 +237,21 @@ class TestMonitorSession:
         assert all(sess._phases[p].num_captures == 1
                    for p in sess.phase_names())
         assert sess.compile_seconds > 0
+
+    def test_jitted_fn_captured_as_is_after_it_ran(self, mesh8):
+        """A jitted function the caller already ran is lowered as it is,
+        and its cached trace does not hide its psum from the interceptor."""
+        f = jax.jit(jax.shard_map(lambda x: jax.lax.psum(x, "data"),
+                                  mesh=mesh8, in_specs=P("data"),
+                                  out_specs=P()))
+        x = jnp.ones((8, 4))
+        f(x)
+        sess = MonitorSession(mesh=mesh8)
+        cap = sess.capture(f, jax.ShapeDtypeStruct(x.shape, x.dtype))
+        assert [ev.primitive for ev in cap.traced] == ["psum"]
+        assert [op.kind for op in cap.ops] == ["all-reduce"]
+        assert sess.last_lowered.as_text() == f.lower(x).as_text()
+        np.testing.assert_array_equal(f(x), jnp.full((2, 4), 4.0))
 
     def test_ops_are_phase_tagged(self, phased_session):
         phases = {op.phase for op in phased_session.compiled_ops}

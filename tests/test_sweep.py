@@ -19,6 +19,22 @@ class TestMeshSpecs:
         with pytest.raises(ValueError):
             sweep.parse_mesh("2x2x2x2")
 
+    def test_mesh_larger_than_host_is_a_clean_error(self):
+        """A mesh beyond the default backend's devices is refused before
+        anything compiles, naming the count and the CPU-modelling route --
+        never a silent switch to other devices."""
+        with pytest.raises(ValueError, match=r"needs 16 devices .* has 8 "
+                                             r"cpu .*JAX_PLATFORMS=cpu"):
+            sweep.run_sweep(["paper"], ["4x2", "16"], ["ring"])
+
+    def test_cli_mesh_larger_than_host_exits_2(self, capsys):
+        from repro import cli
+        rc = cli.main(["sweep", "--configs", "paper", "--meshes", "4x4",
+                       "--no-cache"])
+        assert rc == 2
+        err = capsys.readouterr().err
+        assert err.startswith("error: mesh 4x4 needs 16 devices")
+
 
 class TestRegistry:
     def test_paper_apps_and_archs_present(self):

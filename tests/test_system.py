@@ -28,6 +28,34 @@ class TestTrainDriver:
         assert out.shape == (2, 4)
 
 
+class TestCompileCache:
+    """One persistent-cache location: $JAX_COMPILATION_CACHE_DIR when set,
+    else the fixed <checkout>/.jax_cache."""
+
+    @pytest.fixture
+    def restore_cache_dir(self):
+        before = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", before)
+
+    def test_checkout_dir_when_unset(self, monkeypatch, restore_cache_dir):
+        import os
+        from repro.compat import enable_compile_cache
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        path = enable_compile_cache()
+        checkout = os.path.dirname(os.path.dirname(os.path.abspath(
+            __file__)))
+        assert path == os.path.join(checkout, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+
+    def test_env_dir_wins(self, monkeypatch, tmp_path, restore_cache_dir):
+        from repro.compat import enable_compile_cache
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+
+
 class TestServing:
     def test_greedy_generation_deterministic(self, mesh8):
         from repro import configs
@@ -40,9 +68,55 @@ class TestServing:
         params = model.init(jax.random.PRNGKey(0))
         prompts = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0,
                                      cfg.vocab_size)
-        a = generate(model, params, prompts, shd, steps=6, max_len=32)
-        b = generate(model, params, prompts, shd, steps=6, max_len=32)
+        a, logits_a = generate(model, params, prompts, shd, steps=6,
+                               max_len=32)
+        b, logits_b = generate(model, params, prompts, shd, steps=6,
+                               max_len=32)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(np.asarray(logits_a),
+                                      np.asarray(logits_b))
+        # greedy: every token is the argmax of the logits it came from
+        assert logits_a.shape == (2, 6, cfg.vocab_size)
+        np.testing.assert_array_equal(np.asarray(a),
+                                      np.asarray(logits_a.argmax(-1)))
+
+    def test_monitor_captures_the_served_steps(self):
+        """The launcher's report describes the program it served: each
+        capture is the served step's own lowering, with its shardings."""
+        from repro.core import hlo_cost
+        from repro.core.session import _cost_analysis, _memory_stats
+        from repro.launch import serve
+        from repro.serve.serve import token_sharding
+        args = serve.parse_args(["--arch", "granite_3_2b", "--mesh", "2x2",
+                                 "--batch", "2", "--prompt-len", "8",
+                                 "--tokens", "3"])
+        mesh, shd, model, params = serve.build(args)
+        prompts = serve.prompts_for(args, model.cfg.vocab_size)
+        steps = serve.serve_steps(model, shd, args)
+        out, _ = serve.serve(model, params, shd, prompts, args.tokens, steps)
+        assert out.shape == (2, 3)
+        prefill, decode = steps
+        tok_sh = token_sharding(shd, args.batch)
+        batch = {"tokens": jax.device_put(prompts, tok_sh)}
+        _, cache = prefill(params, batch)
+        served = [prefill.lower(params, batch), decode.lower(
+            params, cache, {"tokens": jax.device_put(out[:, :1], tok_sh)})]
+
+        sess = serve.monitor(model, steps, mesh, args.batch, args.prompt_len,
+                             name="serve")
+        assert sess.phase_names() == ["prefill", "decode"]
+        # what the report says of each program: its collectives, its cost
+        # and its memory, as the served step's own compile gives them
+        for cap, lowered in zip(sess.captures, served):
+            compiled = lowered.compile()
+            ops = hlo_cost.analyze_hlo(compiled.as_text()).collectives
+            assert ([(op.kind, op.payload_bytes, op.replica_groups)
+                     for op in cap.ops]
+                    == [(op.kind, op.payload_bytes, op.replica_groups)
+                        for op in ops]), cap.name
+            assert cap.cost == _cost_analysis(compiled), cap.name
+            assert cap.memory_stats == _memory_stats(compiled), cap.name
+        assert any(cap.ops for cap in sess.captures)
 
 
 class TestConfigs:

@@ -141,19 +141,15 @@ class TestDDP:
     def test_compiler_combines_allreduces(self, mesh_dp):
         """Beyond-paper: XLA's combiner does DDP bucketing automatically.
 
-        Old jaxlibs never run the all-reduce combiner on CPU
-        (``repro.compat.has_allreduce_combiner`` probes the actual
-        behavior); there the same guarantee -- far fewer all-reduces than
-        parameters -- must come from our explicit bucketed mode instead, so
-        that is the path asserted.
+        The naive one-psum-per-tensor step is what is compiled: the
+        installed XLA's all-reduce combiner must merge those psums far
+        below one all-reduce per parameter.
         """
-        from repro.compat import has_allreduce_combiner
         from repro.core import parse_hlo_collectives
         model, params, batch = self._setup(mesh_dp)
         ef = ddp.init_error_feedback(params)
-        mode = "per_param" if has_allreduce_combiner() else "bucketed"
-        step = ddp.make_ddp_train_step(model.loss_fn, mesh_dp, mode=mode,
-                                       bucket_mb=4.0)
+        step = ddp.make_ddp_train_step(model.loss_fn, mesh_dp,
+                                       mode="per_param")
         hlo = step.lower(params, ef, batch).compile().as_text()
         ops = [o for o in parse_hlo_collectives(hlo)
                if o.kind == "all-reduce"]
