@@ -24,7 +24,7 @@ import traceback
 from benchmarks import common
 
 BENCHES = ("table1", "table2", "table3", "fig3", "links", "matrix",
-           "schedule", "overhead", "roofline", "scale", "trace")
+           "schedule", "roofline", "scale", "trace")
 
 _MODS = {
     "table1": "benchmarks.table1_collective_bytes",
@@ -34,7 +34,6 @@ _MODS = {
     "links": "benchmarks.link_utilization",
     "matrix": "benchmarks.matrix_build",
     "schedule": "benchmarks.schedule_eval",
-    "overhead": "benchmarks.overhead",
     "roofline": "benchmarks.roofline_table",
     "scale": "benchmarks.scale_curve",
     "trace": "benchmarks.trace_ingest",

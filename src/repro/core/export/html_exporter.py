@@ -24,7 +24,7 @@ import os
 
 import numpy as np
 
-from .. import reporter
+from .. import reporter, spans
 from ..sparse import is_sparse
 
 # 13-step sequential blue ramp (steps 100..700 of the reference palette);
@@ -435,7 +435,9 @@ def render_dashboard(reports, title: str = "Communication matrices") -> str:
 
 def export_html(reports, path: str, title: str = "Communication matrices") -> str:
     os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
-    with open(path, "w") as f:
+    # one report's export joins its session's spans; a list's stands alone
+    group = None if isinstance(reports, (list, tuple)) else reports.span_group
+    with spans.span("export.html", group), open(path, "w") as f:
         f.write(render_dashboard(reports, title))
     return path
 

@@ -4,6 +4,7 @@ from __future__ import annotations
 import json
 import os
 
+from .. import spans
 from . import serialize
 
 
@@ -19,7 +20,8 @@ def export_json(report, path: str, *, include_hlo: bool = False,
     lint findings.
     """
     os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
-    with open(path, "w") as f:
+    with spans.span("export.json", report.span_group), \
+            open(path, "w") as f:
         json.dump(serialize.report_to_dict(
             report, include_hlo=include_hlo,
             include_schedules=include_schedules,

@@ -88,6 +88,10 @@ class CommReport:
     # (:mod:`repro.core.trace`): source frontend, trace path, clock
     # alignment, device mapping.  None for purely modeled reports.
     trace_meta: Optional[dict] = None
+    # the group of the session's spans (repro.core.spans), which the
+    # report's exports join; None for loaded and imported reports
+    span_group: Optional[int] = dataclasses.field(default=None,
+                                                  compare=False)
 
     # -- lazy algorithm/phase-bound views ---------------------------------
     def view(self, algorithm: Optional[str] = None,

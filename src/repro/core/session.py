@@ -37,13 +37,12 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import time
 from typing import Any, Iterable, Optional
 
 import jax
 import numpy as np
 
-from . import cost_models, decompose, hlo_cost
+from . import cost_models, decompose, hlo_cost, spans
 from .events import (CollectiveOp, HostTransfer, PhaseRecord, TraceEvent)
 from .interceptor import CollectiveInterceptor, traced_summary
 from .topology import MeshTopology
@@ -121,6 +120,9 @@ class MonitorSession:
         # True = COO SparseCommMatrix, False = dense, None = auto by
         # device count (views.SPARSE_DEVICE_THRESHOLD)
         self.sparse = sparse
+        # the group of every span of this session's captures, views and
+        # exports (repro.core.spans)
+        self.span_group = spans.new_group()
         self.topo = MeshTopology.from_mesh(mesh) if mesh is not None else None
         self.num_devices = (int(np.prod(mesh.devices.shape))
                             if mesh is not None else jax.device_count())
@@ -214,16 +216,21 @@ class MonitorSession:
             # from the interceptor; the next call of fn compiles again
             fn.clear_cache()
 
-        t0 = time.perf_counter()
-        with CollectiveInterceptor(mesh=self.mesh) as icpt:
-            lowered = jitted.lower(*args, **kwargs)
-        t1 = time.perf_counter()
-        compiled = lowered.compile()
-        t2 = time.perf_counter()
-
-        hlo_text = compiled.as_text()
-        # loop-aware extraction: ops inside while bodies carry trip weights
-        ops = hlo_cost.analyze_hlo(hlo_text).collectives
+        with spans.group(self.span_group):
+            with spans.span("capture.lower") as lower:
+                with CollectiveInterceptor(mesh=self.mesh) as icpt:
+                    lowered = jitted.lower(*args, **kwargs)
+            with spans.span("capture.compile") as comp:
+                compiled = lowered.compile()
+            with spans.span("capture.hlo_text"):
+                hlo_text = compiled.as_text()
+            with spans.span("capture.analyze_hlo"):
+                # loop-aware extraction: ops inside while bodies carry trip
+                # weights
+                ops = hlo_cost.analyze_hlo(hlo_text).collectives
+            with spans.span("capture.analyses"):
+                cost = _cost_analysis(compiled)
+                memory_stats = _memory_stats(compiled)
         if op_transform is not None:
             ops = [op_transform(op) or op for op in ops]
         for op in ops:
@@ -237,10 +244,10 @@ class MonitorSession:
             phase=phase_name,
             ops=ops,
             traced=events,
-            trace_seconds=t1 - t0,
-            compile_seconds=t2 - t1,
-            cost=_cost_analysis(compiled),
-            memory_stats=_memory_stats(compiled),
+            trace_seconds=lower.seconds,
+            compile_seconds=comp.seconds,
+            cost=cost,
+            memory_stats=memory_stats,
             hlo_text=hlo_text,
         )
         self.captures.append(cap)
@@ -345,25 +352,28 @@ class MonitorSession:
         """
         from .monitor import CommReport   # deferred: monitor imports us
 
-        v = self.view()
-        rep = CommReport(
-            name=name or self.name,
-            num_devices=self.num_devices,
-            traced=list(self.traced),
-            compiled_ops=list(self.compiled_ops),
-            traced_summary=traced_summary(self.traced),
-            compiled_summary=v.summary,
-            matrix=v.matrix,
-            per_primitive=v.per_primitive,
-            cost=self._merged_cost(),
-            memory_stats=self._merged_memory_stats(),
-            trace_seconds=self.trace_seconds,
-            compile_seconds=self.compile_seconds,
-            topo=self.topo,
-            host_transfers=list(self.host_transfers),
-            algorithm=self.algorithm,
-            phases=[dataclasses.replace(p) for p in self._phases.values()],
-        )
+        with spans.group(self.span_group):     # the views' spans
+            v = self.view()
+            rep = CommReport(
+                name=name or self.name,
+                num_devices=self.num_devices,
+                traced=list(self.traced),
+                compiled_ops=list(self.compiled_ops),
+                traced_summary=traced_summary(self.traced),
+                compiled_summary=v.summary,
+                matrix=v.matrix,
+                per_primitive=v.per_primitive,
+                cost=self._merged_cost(),
+                memory_stats=self._merged_memory_stats(),
+                trace_seconds=self.trace_seconds,
+                compile_seconds=self.compile_seconds,
+                topo=self.topo,
+                host_transfers=list(self.host_transfers),
+                algorithm=self.algorithm,
+                phases=[dataclasses.replace(p)
+                        for p in self._phases.values()],
+                span_group=self.span_group,
+            )
         if self.captures:
             rep._lowered = self.last_lowered
             rep._compiled = self.last_compiled

@@ -23,7 +23,7 @@ from typing import Iterable, Optional
 
 import numpy as np
 
-from . import comm_matrix, cost_models, hlo_parser
+from . import comm_matrix, cost_models, hlo_parser, spans
 from .decompose import ScheduleBatch
 from .events import CollectiveOp, HostTransfer
 from .sparse import SPARSE_DEVICE_THRESHOLD
@@ -128,11 +128,12 @@ class CommView:
         heatmaps, exporters) accepts both.
         """
         def build():
-            mat = comm_matrix.matrix_for_schedules(
-                self.ops, self.schedule_batch(), self.num_devices,
-                sparse=self.use_sparse)
-            if self.host_transfers:
-                comm_matrix.add_host_transfers(mat, self.host_transfers)
+            with spans.span("view.matrix"):
+                mat = comm_matrix.matrix_for_schedules(
+                    self.ops, self.schedule_batch(), self.num_devices,
+                    sparse=self.use_sparse)
+                if self.host_transfers:
+                    comm_matrix.add_host_transfers(mat, self.host_transfers)
             return mat
         return self._cached("matrix", build)
 
@@ -140,10 +141,11 @@ class CommView:
     def per_primitive(self) -> dict:
         """Paper Fig. 3: one matrix per collective primitive."""
         def build():
-            return {k: comm_matrix.matrix_for_schedules(
-                        self.ops, self.schedule_batch(), self.num_devices,
-                        kinds={k}, sparse=self.use_sparse)
-                    for k in sorted({op.kind for op in self.ops})}
+            with spans.span("view.per_primitive"):
+                return {k: comm_matrix.matrix_for_schedules(
+                            self.ops, self.schedule_batch(), self.num_devices,
+                            kinds={k}, sparse=self.use_sparse)
+                        for k in sorted({op.kind for op in self.ops})}
         return self._cached("per_primitive", build)
 
     @property
@@ -168,9 +170,11 @@ class CommView:
         phase columns, the Perfetto exporter slices its per-op phase
         seconds.  Built with fallback warnings on, like the placement
         always warned."""
-        return self._cached("schedule_batch", lambda: (
-            ScheduleBatch.from_ops(self.ops, self.algorithm, self.topo,
-                                   warn=True)))
+        def build():
+            with spans.span("view.schedule"):
+                return ScheduleBatch.from_ops(self.ops, self.algorithm,
+                                              self.topo, warn=True)
+        return self._cached("schedule_batch", build)
 
     def schedules(self) -> list:
         """One :class:`~repro.core.decompose.CollectiveSchedule` per op

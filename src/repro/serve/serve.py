@@ -14,6 +14,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core import spans
 from repro.parallel import Sharder
 
 
@@ -91,9 +92,6 @@ def generate(model, params, prompts, shd: Sharder, *, steps: int = 16,
                        temperature=temperature)
     prefill, decode = serve_steps or make_serve_steps(model, shd, scfg)
     tok_sh = token_sharding(shd, scfg.batch)
-    logits, cache = prefill(params,
-                            {"tokens": jax.device_put(prompts, tok_sh)})
-    logits = logits.astype(jnp.float32)
     rng = rng if rng is not None else jax.random.PRNGKey(0)
 
     def sample(logits, rng):
@@ -101,14 +99,24 @@ def generate(model, params, prompts, shd: Sharder, *, steps: int = 16,
             return jax.random.categorical(rng, logits / temperature, axis=-1)
         return jnp.argmax(logits, axis=-1)
 
-    tok = sample(logits, rng)
+    # the spans of one batch share a group (repro.core.spans)
+    group = spans.new_group()
+    with spans.span("serve.prefill", group):
+        logits, cache = prefill(params,
+                                {"tokens": jax.device_put(prompts, tok_sh)})
+        logits = logits.astype(jnp.float32)
+        tok = sample(logits, rng)
+        batch = {"tokens": jax.device_put(tok[:, None], tok_sh)}
     toks, all_logits = [tok], [logits]
-    for i in range(steps - 1):
-        rng, k = jax.random.split(rng)
-        logits, cache = decode(
-            params, cache, {"tokens": jax.device_put(tok[:, None], tok_sh)})
-        logits = logits[:, -1].astype(jnp.float32)
-        tok = sample(logits, k)
+    for _ in range(steps - 1):
+        with spans.span("serve.decode", group):
+            spans.count("serve.decode_steps")
+            logits, cache = decode(params, cache, batch)
+        with spans.span("serve.sample", group):
+            rng, k = jax.random.split(rng)
+            logits = logits[:, -1].astype(jnp.float32)
+            tok = sample(logits, k)
+            batch = {"tokens": jax.device_put(tok[:, None], tok_sh)}
         toks.append(tok)
         all_logits.append(logits)
     return jnp.stack(toks, axis=1), jnp.stack(all_logits, axis=1)
