@@ -59,8 +59,10 @@ per-layer metrics, ``bench/metrics/``):
   serve.prefill, serve.decode, serve.sample
                                       serve.retrace_s, serve.dispatch_ms
 
-The one counter, ``serve.decode_steps`` on each ``serve.decode`` span, is
-serve.dispatch_ms's divisor.
+Two counters on each ``serve.decode`` span: ``serve.decode_steps``,
+serve.dispatch_ms's divisor, and ``serve.cache_donated``, one where the
+decode call consumed the cache it was given (donated, so updated in
+place), which serve.cache_donated_share reads over the steps.
 """
 from __future__ import annotations
 

@@ -104,8 +104,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, opt_name=None,
         scfg = ServeConfig(max_len=shape.seq_len, batch=shape.global_batch)
         params_sh = shd.tree_shardings(model.shapes(), model.axes())
         step, cache_sh = make_decode_step(model, shd, scfg,
-                                          params_sh=params_sh,
-                                          donate_cache=True)
+                                          params_sh=params_sh)
         cache_shapes = model.cache_shapes(shape.global_batch, shape.seq_len)
         lowered = step.lower(model.shapes(), cache_shapes, batch)
         model_flops = roofline.forward_model_flops(

@@ -160,38 +160,58 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
     return outs.swapaxes(0, 1).reshape(b, sq, h, dh)
 
 
-def decode_attention(q, k_cache, v_cache, cache_len, *, window: int = 0,
-                     ring: bool = False):
-    """Single-position decode: q (B,1,H,dh) over a (B,L,KVH,dh) cache.
+def decode_attention(q, k_cache, v_cache, k_new, v_new, cache_len, *,
+                     window: int = 0, ring: bool = False):
+    """Single-position decode: q (B,1,H,dh) over a cache of rows
+    (B,L,KVH*dh), one row per position holding every kv head's lanes, and
+    over the new token's own k_new, v_new (B,1,KVH,dh).
 
-    ``cache_len`` (scalar int) is the number of valid cache entries; the new
-    token's k/v must already be written (at ``(cache_len-1) % L`` if ``ring``).
-    A ring cache keeps only the last ``L`` (== window) positions — this is
-    what bounds long_500k decode memory for windowed-attention archs.
+    ``cache_len`` (scalar int) is the number of valid positions, the new
+    token's included; its slot (``(cache_len-1) % L`` if ``ring``) is left
+    out of the cache, so a step attends before it writes its row.  A ring
+    cache keeps only the last ``L`` (== window) positions — this is what
+    bounds long_500k decode memory for windowed-attention archs.
+
+    Each query head meets its kv head's lanes of a row through the query
+    spread block-diagonally over the row: the cache is read as it lies,
+    for ``KVH`` times the multiply-adds, where a decode step is bound by
+    the bytes it reads.
     """
     b, _, h, dh = q.shape
-    _, lmax, kvh, _ = k_cache.shape
+    _, lmax, width = k_cache.shape
+    kvh = width // dh
     g = h // kvh
     scale = dh ** -0.5
-    qg = q.reshape(b, kvh, g, dh)
-    s = jnp.einsum("bkgd,bskd->bkgs",
-                   (qg * scale).astype(jnp.float32),
-                   k_cache.astype(jnp.float32))
+    eye = jnp.eye(kvh, dtype=k_cache.dtype)[:, None, :, None]
+    qg = (q.reshape(b, kvh, g, dh) * scale).astype(k_cache.dtype)
+    q_rows = (qg[:, :, :, None, :] * eye).reshape(b, h, width)
+    s = jnp.einsum("bhc,bsc->bhs", q_rows, k_cache,
+                   preferred_element_type=jnp.float32).reshape(b, kvh, g, lmax)
     kpos = jnp.arange(lmax)
     if ring:
         # slot i holds absolute position cache_len-1-age, age=(cache_len-1-i)%L
         age = jnp.mod(cache_len - 1 - kpos, lmax)
-        mask = age < cache_len  # slot written at least once
+        mask = (age > 0) & (age < cache_len)  # written before this step
         if window > 0:
             mask &= age < window
     else:
-        mask = kpos < cache_len
+        mask = kpos < cache_len - 1
         if window > 0:
             mask &= kpos >= cache_len - window
     s = jnp.where(mask[None, None, None, :], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgs,bskd->bkgd", p.astype(v_cache.dtype), v_cache)
-    return out.reshape(b, 1, h, dh)
+    # softmax over the cache's positions and the new one
+    k_new, v_new = (a.reshape(b, kvh, dh).astype(jnp.float32)
+                    for a in (k_new, v_new))
+    s_new = jnp.einsum("bkgd,bkd->bkg", qg.astype(jnp.float32), k_new)
+    m = jnp.maximum(s.max(axis=-1), s_new)
+    e, e_new = jnp.exp(s - m[..., None]), jnp.exp(s_new - m)
+    total = e.sum(axis=-1) + e_new
+    p = (e / total[..., None]).reshape(b, h, lmax).astype(v_cache.dtype)
+    out = jnp.einsum("bhs,bsc->bhc", p, v_cache,
+                     preferred_element_type=jnp.float32)
+    out = (out.reshape(b, kvh, g, kvh, dh) * eye).sum(axis=3)
+    out = out + (e_new / total)[..., None] * v_new[:, :, None]
+    return out.astype(v_cache.dtype).reshape(b, 1, h, dh)
 
 
 # ---------------------------------------------------------------------------
@@ -203,7 +223,16 @@ def attention_block(params, x, cfg: ModelConfig, shd, *,
                     n_kv_heads: Optional[int] = None):
     """Returns (out, new_cache).  ``cache=None`` -> training/prefill w/o cache.
 
-    cache = {"k": (B,L,KVH,dh), "v": ..., "len": int32 scalar} -> decode step.
+    Prefill (``s > 1``): cache = {"k": (B,L,KVH*dh), "v": ...} gives the
+    cache's shape; new_cache holds the sequence's tail and its "len".
+
+    Decode (``s == 1``): cache = {"k": (n,B,L,KVH*dh), "v": ..., "len":
+    int32 scalar, "layer": int32 scalar} holds every layer's rows; the
+    step attends over layer ``layer``'s rows as they stand and its own k/v,
+    and new_cache = {"k", "v"} is the stack with its row written at "len"
+    (the ring slot for a window's ring buffer): the one write the step
+    makes to this layer's cache, in place where the stack is carried
+    through the layer scan and donated.
     """
     from repro.kernels.flash_attention import ops as flash_ops
 
@@ -266,8 +295,8 @@ def attention_block(params, x, cfg: ModelConfig, shd, *,
         new_cache = None
         if cache is not None:
             lmax = cache["k"].shape[1]
-            kc = k_gqa.astype(cache["k"].dtype)
-            vc = v_gqa.astype(cache["v"].dtype)
+            kc = k_gqa.reshape(b, s, nkv * dh).astype(cache["k"].dtype)
+            vc = v_gqa.reshape(b, s, nkv * dh).astype(cache["v"].dtype)
             if s >= lmax:            # ring layout: slot j holds pos p, p%lmax==j
                 kc, vc = kc[:, -lmax:], vc[:, -lmax:]
                 kc = jnp.roll(kc, s % lmax, axis=1)
@@ -278,22 +307,27 @@ def attention_block(params, x, cfg: ModelConfig, shd, *,
             new_cache = {"k": kc, "v": vc,
                          "len": jnp.full((), s, jnp.int32)}
     else:
-        pos = cache["len"]                                    # scalar int32
-        lmax = cache["k"].shape[1]
+        pos, layer = cache["len"], cache["layer"]             # scalar int32
+        lmax = cache["k"].shape[2]
         ring = win > 0 and lmax <= win                        # ring buffer
         positions = jnp.broadcast_to(pos[None, None], (b, 1))
         q = shd.constraint(apply_rope(q, positions, cfg.rope_theta), q_ax)
         k = shd.constraint(apply_rope(k, positions, cfg.rope_theta), kv_ax)
         v = shd.constraint(v, kv_ax)
-        slot = jnp.mod(pos, lmax) if ring else pos
-        k_cache = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k.astype(cache["k"].dtype), slot, axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v.astype(cache["v"].dtype), slot, axis=1)
-        out = decode_attention(q, k_cache, v_cache, pos + 1, window=win,
+        k = k.astype(cache["k"].dtype)
+        v = v.astype(cache["v"].dtype)
+        rows = [jax.lax.dynamic_index_in_dim(cache[name], layer,
+                                             keepdims=False,
+                                             allow_negative_indices=False)
+                for name in ("k", "v")]
+        out = decode_attention(q, *rows, k, v, pos + 1, window=win,
                                ring=ring)
         out = shd.constraint(out, q_ax)
-        new_cache = {"k": k_cache, "v": v_cache, "len": pos + 1}
+        slot = jnp.mod(pos, lmax) if ring else pos
+        new_cache = {name: jax.lax.dynamic_update_slice(
+                         cache[name], new.reshape(1, b, 1, nkv * dh),
+                         (layer, 0, slot, 0), allow_negative_indices=False)
+                     for name, new in (("k", k), ("v", v))}
 
     out = jnp.einsum("bsk,kd->bsd",
                      out.reshape(b, -1, nh * dh).astype(dt),
@@ -304,21 +338,17 @@ def attention_block(params, x, cfg: ModelConfig, shd, *,
 def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, *,
                   n_kv_heads: Optional[int] = None, dtype: str = "bfloat16",
                   window: Optional[int] = None):
+    """A zero cache of one row of KVH*dh values per position.  A decode
+    step writes one row; a TPU lays a (B,L,KVH,dh) cache with positions
+    minor instead (dh 64 fills half a 128-lane tile), where writing one
+    position is a masked store into every tile of the layer."""
     nkv = n_kv_heads or cfg.n_kv_heads
     win = cfg.attn_window if window is None else window
     if win > 0:
         max_len = min(max_len, win)                           # ring buffer
-    shape = (batch, max_len, nkv, cfg.dh)
+    shape = (batch, max_len, nkv * cfg.dh)
     return {
         "k": jnp.zeros(shape, jnp.dtype(dtype)),
         "v": jnp.zeros(shape, jnp.dtype(dtype)),
         "len": jnp.zeros((), jnp.int32),
-    }
-
-
-def kv_cache_axes():
-    return {
-        "k": ("batch", "kv_seq", "kv_heads", None),
-        "v": ("batch", "kv_seq", "kv_heads", None),
-        "len": (),
     }

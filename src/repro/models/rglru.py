@@ -269,8 +269,8 @@ class GriffinLM:
               "conv": ("stack", "batch", None, "rnn")}
         return {
             "rec1": ra, "rec2": ra,
-            "attn": {"k": ("stack", "batch", "kv_seq", "kv_heads", None),
-                     "v": ("stack", "batch", "kv_seq", "kv_heads", None)},
+            "attn": {"k": ("stack", "batch", "kv_seq", "kv_heads"),
+                     "v": ("stack", "batch", "kv_seq", "kv_heads")},
             "tail": ra if self.n_tail else {},
             "len": (),
         }
@@ -279,25 +279,22 @@ class GriffinLM:
         cfg = self.cfg
         x = layers.embed(params["embed"], batch["tokens"], cfg, shd)
 
-        def body(x, sp, st):
-            kv = {"k": st["attn_k"], "v": st["attn_v"], "len": cache["len"]}
+        def scan_body(carry, xs):
+            x, kv = carry
+            sp, st, layer = xs
             x, s1 = self._rec_layer(sp["rec1"], x, shd, state=st["rec1"])
             x, s2 = self._rec_layer(sp["rec2"], x, shd, state=st["rec2"])
-            x, kv2 = self._attn_layer(sp["attn"], x, shd, cache=kv)
-            return x, {"rec1": s1, "rec2": s2,
-                       "attn_k": kv2["k"], "attn_v": kv2["v"]}
+            x, kv = self._attn_layer(sp["attn"], x, shd, cache={
+                **kv, "len": cache["len"], "layer": layer})
+            return (x, kv), {"rec1": s1, "rec2": s2}
 
-        def scan_body(carry, xs):
-            sp, st = xs
-            x, new = body(carry, sp, st)
-            return x, new
-
-        sts = {"rec1": cache["rec1"], "rec2": cache["rec2"],
-               "attn_k": cache["attn"]["k"], "attn_v": cache["attn"]["v"]}
-        x, new_sts = jax.lax.scan(scan_body, x, (params["super"], sts))
+        sts = {"rec1": cache["rec1"], "rec2": cache["rec2"]}
+        (x, kv), new_sts = jax.lax.scan(
+            scan_body, (x, cache["attn"]),
+            (params["super"], sts, jnp.arange(self.n_super)))
         new_cache = {
             "rec1": new_sts["rec1"], "rec2": new_sts["rec2"],
-            "attn": {"k": new_sts["attn_k"], "v": new_sts["attn_v"]},
+            "attn": kv,
             "tail": cache.get("tail", {}),
             "len": cache["len"] + 1,
         }

@@ -125,39 +125,34 @@ class TransformerLM:
         }
 
     def cache_shapes(self, batch: int, max_len: int, dtype: str = "bfloat16"):
-        cfg = self.cfg
-        win = cfg.attn_window
-        L = min(max_len, win) if win > 0 else max_len
-        shape = (cfg.n_layers, batch, L, cfg.n_kv_heads, cfg.dh)
-        return {
-            "k": jax.ShapeDtypeStruct(shape, jnp.dtype(dtype)),
-            "v": jax.ShapeDtypeStruct(shape, jnp.dtype(dtype)),
-            "len": jax.ShapeDtypeStruct((), jnp.int32),
-        }
+        return jax.eval_shape(
+            lambda: self.init_cache(batch, max_len, dtype))
 
     def cache_axes(self):
         return {
-            "k": ("layers", "batch", "kv_seq", "kv_heads", None),
-            "v": ("layers", "batch", "kv_seq", "kv_heads", None),
+            "k": ("layers", "batch", "kv_seq", "kv_heads"),
+            "v": ("layers", "batch", "kv_seq", "kv_heads"),
             "len": (),
         }
 
     def _stack_decode(self, params, x, cache, shd):
-        """One-token step through all layers, scanning the stacked cache."""
+        """One-token step through all layers.  The stacked cache rides the
+        scan's carry: each layer reads its rows as they stand and writes
+        only its new row into the stack (:func:`attention.attention_block`)."""
 
         def body(carry, xs):
-            x, aux = carry
-            lp, kc, vc = xs
-            layer_cache = {"k": kc, "v": vc, "len": cache["len"]}
-            x, aux, new_cache = self._layer_fn(x, aux, lp, shd,
-                                               cache=layer_cache)
-            return (x, aux), (new_cache["k"], new_cache["v"])
+            x, aux, kv = carry
+            lp, layer = xs
+            x, aux, kv = self._layer_fn(
+                x, aux, lp, shd, cache={**kv, "len": cache["len"],
+                                        "layer": layer})
+            return (x, aux, kv), None
 
-        (x, _), (ks, vs) = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)),
-            (params["layers"], cache["k"], cache["v"]))
-        new_cache = {"k": ks, "v": vs, "len": cache["len"] + x.shape[1]}
-        return x, new_cache
+        kv = {"k": cache["k"], "v": cache["v"]}
+        (x, _, kv), _ = jax.lax.scan(
+            body, (x, jnp.zeros((), jnp.float32), kv),
+            (params["layers"], jnp.arange(self.cfg.n_layers)))
+        return x, {**kv, "len": cache["len"] + x.shape[1]}
 
     def decode_step(self, params, cache, batch, shd):
         """batch: {"tokens": (B,1)} or {"embeds": (B,1,D)} -> (logits, cache)."""

@@ -39,19 +39,18 @@ def token_sharding(shd: Sharder, batch: int):
 
 
 def make_decode_step(model, shd: Sharder, serve_cfg: ServeConfig,
-                     params_sh=None, donate_cache: bool = True,
-                     batch_sh=None):
-    """jit'd decode_step(params, cache, batch) -> (logits, cache)."""
+                     params_sh=None, batch_sh=None):
+    """jit'd decode_step(params, cache, batch) -> (logits, cache).  The
+    cache is donated: the step writes its new entries into it in place,
+    and the cache passed in is deleted."""
     cache_sh = cache_shardings(model, serve_cfg, shd)
 
     def step(params, cache, batch):
         return model.decode_step(params, cache, batch, shd)
 
-    kw = dict(in_shardings=(params_sh, cache_sh, batch_sh),
-              out_shardings=(None, cache_sh))
-    if donate_cache:
-        kw["donate_argnums"] = (1,)
-    return jax.jit(step, **kw), cache_sh
+    return jax.jit(step, in_shardings=(params_sh, cache_sh, batch_sh),
+                   out_shardings=(None, cache_sh),
+                   donate_argnums=(1,)), cache_sh
 
 
 def make_prefill_step(model, shd: Sharder, serve_cfg: ServeConfig,
@@ -74,7 +73,7 @@ def make_serve_steps(model, shd: Sharder, serve_cfg: ServeConfig,
     batch_sh = {"tokens": token_sharding(shd, serve_cfg.batch)}
     prefill, _ = make_prefill_step(model, shd, serve_cfg, params_sh, batch_sh)
     decode, _ = make_decode_step(model, shd, serve_cfg, params_sh,
-                                 donate_cache=False, batch_sh=batch_sh)
+                                 batch_sh=batch_sh)
     return prefill, decode
 
 
@@ -111,7 +110,10 @@ def generate(model, params, prompts, shd: Sharder, *, steps: int = 16,
     for _ in range(steps - 1):
         with spans.span("serve.decode", group):
             spans.count("serve.decode_steps")
-            logits, cache = decode(params, cache, batch)
+            logits, new_cache = decode(params, cache, batch)
+            if all(a.is_deleted() for a in jax.tree.leaves(cache)):
+                spans.count("serve.cache_donated")
+            cache = new_cache
         with spans.span("serve.sample", group):
             rng, k = jax.random.split(rng)
             logits = logits[:, -1].astype(jnp.float32)

@@ -181,7 +181,11 @@ class TestFlashDecode:
         k = rand(ks[1], (2, 64, 2, 32))
         v = rand(ks[2], (2, 64, 2, 32))
         a = decode_ref(q, k, v, jnp.int32(40))
-        bq = decode_attention(q[:, None], k, v, jnp.int32(40))[:, 0]
+        # the model keeps a row per position and attends before it writes
+        # the new token's (position 39) own k/v
+        rows = [x.reshape(2, 64, 2 * 32) for x in (k, v)]
+        bq = decode_attention(q[:, None], *rows, k[:, 39:40], v[:, 39:40],
+                              jnp.int32(40))[:, 0]
         assert jnp.max(jnp.abs(a - bq)) < 2e-5
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

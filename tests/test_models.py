@@ -89,13 +89,21 @@ class TestPrefillDecodeConsistency:
     """Prefill(tokens) must equal step-by-step decode — the strongest
     correctness property linking the parallel and recurrent forms."""
 
-    @pytest.mark.parametrize("arch", ["granite_3_2b", "qwen3_8b",
-                                      "xlstm_1_3b", "recurrentgemma_2b"])
-    def test_prefill_matches_stepwise_decode(self, arch, shd):
+    @pytest.mark.parametrize("arch,window", [
+        pytest.param("granite_3_2b", 0, id="granite_3_2b"),
+        pytest.param("qwen3_8b", 0, id="qwen3_8b"),
+        pytest.param("xlstm_1_3b", 0, id="xlstm_1_3b"),
+        pytest.param("recurrentgemma_2b", 0, id="recurrentgemma_2b"),
+        # a sliding window of 4 over 8 tokens: the ring slot wraps twice
+        pytest.param("granite_3_2b", 4, id="granite_3_2b-window4"),
+    ])
+    def test_prefill_matches_stepwise_decode(self, arch, window, shd):
         import dataclasses
         # fp32 compute so the tolerance tests logic, not bf16 rounding
         cfg = dataclasses.replace(configs.config(arch, reduced=True),
                                   compute_dtype="float32")
+        if window:
+            cfg = dataclasses.replace(cfg, attn_window=window)
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(1))
         b, s = 2, 8
@@ -105,9 +113,15 @@ class TestPrefillDecodeConsistency:
             lambda p, bb: model.prefill(p, bb, shd))(params, {"tokens": toks})
 
         cache = model.init_cache(b, s)
-        step = jax.jit(lambda p, c, bb: model.decode_step(p, c, bb, shd))
+        # the cache donated, as the served decode takes it: each step
+        # consumes the one the step before returned
+        step = jax.jit(lambda p, c, bb: model.decode_step(p, c, bb, shd),
+                       donate_argnums=1)
         for t in range(s):
+            old = cache
             logits, cache = step(params, cache, {"tokens": toks[:, t:t + 1]})
+            if t:
+                assert all(a.is_deleted() for a in jax.tree.leaves(old))
         np.testing.assert_allclose(
             np.asarray(pf_logits, np.float32),
             np.asarray(logits[:, 0], np.float32), rtol=2e-2, atol=2e-2)

@@ -230,7 +230,9 @@ class TestCallSites:
             assert len(by_name(recs, "serve.prefill")) == 1
             decode = by_name(recs, "serve.decode")
             assert len(decode) == 2 == len(by_name(recs, "serve.sample"))
-            assert all(r["counts"] == {"serve.decode_steps": 1}
+            # every decode step consumed the cache it was given
+            assert all(r["counts"] == {"serve.decode_steps": 1,
+                                       "serve.cache_donated": 1}
                        for r in decode)
 
     def test_the_capture_forces_a_retrace(self, served):

@@ -118,6 +118,40 @@ class TestServing:
             assert cap.memory_stats == _memory_stats(compiled), cap.name
         assert any(cap.ops for cap in sess.captures)
 
+    def test_served_decode_updates_its_cache_in_place(self):
+        """The served decode takes its cache donated and writes only each
+        layer's new entry: the monitor's memory stats show the whole stack
+        aliased, and its temporaries hold no second stack.  XLA:CPU copies
+        a loop-carried bf16 buffer that is read before it is written and
+        widens a layer's slice to f32 (at most one stack and two layers'
+        K+V); the parent program grew 2.4 stacks from 64 to 512 positions.
+        ``test_tpu_compile.py`` holds the chip's compiler to two layers."""
+        from repro import configs
+        from repro.compat import make_mesh
+        from repro.core import MonitorSession
+        from repro.models import build_model
+        from repro.parallel import Sharder
+        from repro.serve import ServeConfig, make_serve_steps
+        mesh = make_mesh((1,), ("data",))
+        shd = Sharder(mesh)
+        model = build_model(configs.config("granite_3_2b", reduced=True))
+        assert model.cfg.n_layers == 4
+        stats, stack = {}, {}
+        for max_len in (64, 512):
+            _, decode = make_serve_steps(
+                model, shd, ServeConfig(max_len=max_len, batch=4))
+            cache = model.cache_shapes(4, max_len)
+            sess = MonitorSession(mesh=mesh, name="decode")
+            cap = sess.capture(decode, model.shapes(), cache, {
+                "tokens": jax.ShapeDtypeStruct((4, 1), jnp.int32)})
+            stats[max_len] = cap.memory_stats
+            stack[max_len] = sum(a.size * a.dtype.itemsize
+                                 for a in jax.tree.leaves(cache))
+            assert stats[max_len]["alias_bytes"] == stack[max_len]
+        layer_kv = (stack[512] - 4) // model.cfg.n_layers
+        growth = stats[512]["temp_bytes"] - stats[64]["temp_bytes"]
+        assert growth < stack[512] + 2 * layer_kv
+
 
 class TestConfigs:
     def test_registry_complete(self):

@@ -111,3 +111,48 @@ def test_resnet18_ddp_allreduce_bytes(topo):
                  if ev.primitive == "psum")
     assert compiled == traced == DDP_PSUM_BYTES
     assert cap.memory_stats["total_bytes"] > 0 and cap.cost["flops"] > 0
+
+
+def test_served_decode_updates_the_cache_in_place(topo):
+    """granite-3-2b's served decode at published widths (4 of its 40
+    layers, a 32-request batch): the cache is donated and aliased, and the
+    step holds no copy of it.  Its temporaries grow by less than two
+    layers' K+V from a 64- to a 640-position cache; donating a decode that
+    rebuilds the stack holds a whole second one (1.68 GB at 40 layers)."""
+    import dataclasses
+
+    from repro import configs
+    from repro.core import MonitorSession
+    from repro.models import build_model
+    from repro.parallel import Sharder
+    from repro.serve import ServeConfig, cache_shardings, make_serve_steps
+
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",),
+                axis_types=(AxisType.Auto,))
+    shd = Sharder(mesh)
+    model = build_model(dataclasses.replace(configs.config("granite_3_2b"),
+                                            n_layers=4))
+    params_sh = shd.tree_shardings(model.shapes(), model.axes())
+
+    def shapes(tree, shardings):
+        return jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=s), tree, shardings)
+
+    batch, stats, stack = 32, {}, {}
+    for max_len in (64, 640):
+        scfg = ServeConfig(max_len=max_len, batch=batch)
+        _, decode = make_serve_steps(model, shd, scfg, params_sh)
+        cache = shapes(model.cache_shapes(batch, max_len),
+                       cache_shardings(model, scfg, shd))
+        tokens = jax.ShapeDtypeStruct((batch, 1), jnp.int32,
+                                      sharding=NamedSharding(mesh, P()))
+        cap = MonitorSession(mesh=mesh, name="decode").capture(
+            decode, shapes(model.shapes(), params_sh), cache,
+            {"tokens": tokens})
+        stats[max_len] = cap.memory_stats
+        stack[max_len] = sum(a.size * a.dtype.itemsize
+                             for a in jax.tree.leaves(cache))
+        # the whole cache, the length scalar padded to its tile
+        assert stats[max_len]["alias_bytes"] >= stack[max_len]
+    layer_kv = (stack[640] - 4) // 4
+    assert stats[640]["temp_bytes"] - stats[64]["temp_bytes"] < 2 * layer_kv
