@@ -186,6 +186,7 @@ _INSTR_RE = re.compile(
 
 
 _PROMOTED_RE = re.compile(r"to_apply=%?\S*promoted")
+_CONTEXT = Shape("u32", ())
 
 
 def parse_hlo_collectives(hlo_text: str) -> list[CollectiveOp]:
@@ -212,9 +213,13 @@ def parse_hlo_collectives(hlo_text: str) -> list[CollectiveOp]:
                 for s in result_shapes]
         # async-start results repeat operand + result; dedupe: the final shape
         # tuple of a start op is ((operands), results, ...) -- keep the result
-        # entries only for the common (operand, result, u32[]) layout.
+        # entries only.  A TPU's collective-permute-start ends in two u32[]
+        # context scalars, (op, result, u32[], u32[]): drop them first.
         if _start and len(result_shapes) >= 2:
             # all-gather-start: (op, result); all-reduce-start: same shape
+            if (kind == "collective-permute" and len(result_shapes) == 4
+                    and result_shapes[2:] == [_CONTEXT, _CONTEXT]):
+                result_shapes = result_shapes[:2]
             half = len(result_shapes) // 2
             result_shapes = result_shapes[half:] or result_shapes
         groups = parse_replica_groups(line)

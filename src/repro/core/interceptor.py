@@ -40,6 +40,7 @@ _HOOKED_PRIMITIVES = {
     "ragged_all_to_all_p": ("ragged_all_to_all", "AllToAll"),
     "ppermute_p": ("ppermute", "SendRecv"),
     "pgather_p": ("pgather", "Gather"),
+    "pbroadcast_p": ("pbroadcast", "Broadcast"),
 }
 
 _lock = threading.Lock()

@@ -53,8 +53,10 @@ per-layer metrics, ``bench/metrics/``):
   capture.hlo_text                    capture.hlo_text_s
   capture.analyze_hlo                 capture.analyze_s
   capture.analyses                    capture.analyses_s
-  view.schedule                       report.schedule_s
-  view.matrix, view.per_primitive     report.place_s
+  view.schedule                       report.schedule_s,
+                                      report.schedule_ms_per_shape
+  view.matrix, view.per_primitive     report.place_s,
+                                      report.place_us_per_edge
   export.json, export.html            report.export_json_s, _html_s
   serve.prefill, serve.decode, serve.sample
                                       serve.retrace_s, serve.dispatch_ms
@@ -62,7 +64,12 @@ per-layer metrics, ``bench/metrics/``):
 Two counters on each ``serve.decode`` span: ``serve.decode_steps``,
 serve.dispatch_ms's divisor, and ``serve.cache_donated``, one where the
 decode call consumed the cache it was given (donated, so updated in
-place), which serve.cache_donated_share reads over the steps.
+place), which serve.cache_donated_share reads over the steps.  One on
+each ``view.schedule`` span, ``view.shapes``: the distinct collective
+shapes it decomposed; and one on each ``view.matrix`` and
+``view.per_primitive`` span, ``view.edges``: the (source, destination)
+entries it placed.  They divide report.schedule_ms_per_shape and
+report.place_us_per_edge.
 """
 from __future__ import annotations
 

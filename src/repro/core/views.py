@@ -129,9 +129,11 @@ class CommView:
         """
         def build():
             with spans.span("view.matrix"):
+                batch = self.schedule_batch()
                 mat = comm_matrix.matrix_for_schedules(
-                    self.ops, self.schedule_batch(), self.num_devices,
+                    self.ops, batch, self.num_devices,
                     sparse=self.use_sparse)
+                self._count_edges(batch)
                 if self.host_transfers:
                     comm_matrix.add_host_transfers(mat, self.host_transfers)
             return mat
@@ -142,11 +144,21 @@ class CommView:
         """Paper Fig. 3: one matrix per collective primitive."""
         def build():
             with spans.span("view.per_primitive"):
-                return {k: comm_matrix.matrix_for_schedules(
-                            self.ops, self.schedule_batch(), self.num_devices,
-                            kinds={k}, sparse=self.use_sparse)
-                        for k in sorted({op.kind for op in self.ops})}
+                batch = self.schedule_batch()
+                out = {k: comm_matrix.matrix_for_schedules(
+                           self.ops, batch, self.num_devices, kinds={k},
+                           sparse=self.use_sparse)
+                       for k in sorted({op.kind for op in self.ops})}
+                self._count_edges(batch)
+                return out
         return self._cached("per_primitive", build)
+
+    def _count_edges(self, batch: ScheduleBatch) -> None:
+        """Counter ``view.edges`` of the open span: the edges the matrix
+        build just placed, one per (source, destination) entry of each
+        op's schedule."""
+        spans.count("view.edges", sum(
+            batch.edge_cache[id(s)][0].size for s in batch.schedules))
 
     @property
     def summary(self) -> dict:
@@ -172,8 +184,10 @@ class CommView:
         always warned."""
         def build():
             with spans.span("view.schedule"):
-                return ScheduleBatch.from_ops(self.ops, self.algorithm,
-                                              self.topo, warn=True)
+                batch = ScheduleBatch.from_ops(self.ops, self.algorithm,
+                                               self.topo, warn=True)
+                spans.count("view.shapes", batch.num_distinct)
+                return batch
         return self._cached("schedule_batch", build)
 
     def schedules(self) -> list:

@@ -10,6 +10,9 @@ inside ``shard_map``) in three flavours the benchmarks sweep:
   buckets, one AllReduce per bucket (PyTorch default, 25 MiB),
 * optional bf16 compression with fp32 error-feedback on either.
 
+A model with state (BatchNorm's running statistics) adds DDP's buffer
+broadcast: each step ends by giving every chip the first chip's state.
+
 Because these collectives are traced by the application, the interceptor
 (LD_PRELOAD analogue) sees them — this is the path that exercises the
 paper's original workflow end-to-end.
@@ -17,6 +20,7 @@ paper's original workflow end-to-end.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Callable, Optional
 
 import jax
@@ -92,18 +96,46 @@ def allreduce_per_param(grads, axis_name: str):
     return jax.tree.map(lambda g: jax.lax.pmean(g, axis_name), grads)
 
 
+def broadcast_from_first(tree, axis_name: str, n: int):
+    """Every chip's ``tree`` replaced by the axis's first chip's: PyTorch
+    DDP's ``broadcast_buffers``, its buffers coalesced into one flat float32
+    buffer as ``_broadcast_coalesced`` does.
+
+    XLA's ``collective-broadcast`` (``jax.lax.pbroadcast``) lowers on GPUs
+    only; XLA:TPU and XLA:CPU refuse it.  So the buffer is forwarded along
+    the axis, 0 -> 1 -> ... -> n-1, one ``ppermute`` (collective-permute)
+    a hop: NCCL's ring broadcast, the buffer once over each of n-1 links.
+    """
+    leaves, treedef = jax.tree.flatten(tree)
+    flat = jnp.concatenate([x.astype(jnp.float32).reshape(-1)
+                            for x in leaves])
+    idx = jax.lax.axis_index(axis_name)
+    for k in range(1, n):
+        hop = jax.lax.ppermute(flat, axis_name, perm=[(k - 1, k)])
+        flat = jnp.where(idx == k, hop, flat)
+    out, off = [], 0
+    for x in leaves:
+        out.append(flat[off:off + x.size].reshape(x.shape).astype(x.dtype))
+        off += x.size
+    return jax.tree.unflatten(treedef, out)
+
+
 # ---------------------------------------------------------------------------
 # a complete DDP train step (shard_map over the data axis)
 # ---------------------------------------------------------------------------
 def make_ddp_train_step(loss_fn: Callable, mesh, *, axis_name: str = "data",
                         mode: str = "bucketed", bucket_mb: float = 25.0,
-                        compress: bool = False, lr: float = 1e-3):
+                        compress: bool = False, lr: float = 1e-3,
+                        stateful: bool = False):
     """loss_fn(params, batch) -> (loss, metrics).  Params replicated; batch
-    sharded over ``axis_name``.  SGD update inline (the paper's apps)."""
+    sharded over ``axis_name``.  SGD update inline (the paper's apps).
 
-    def step(params, ef, batch):
-        (loss, metrics), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params, batch)
+    ``stateful``: loss_fn(params, state, batch) -> (loss, (metrics,
+    new_state)), and the step is ``(params, state, ef, batch) -> (params,
+    state, ef, loss)``.  Each chip updates the state from its own shard of
+    the batch; the first chip's is then broadcast to every chip
+    (:func:`broadcast_from_first`), so it leaves the step replicated."""
+    def sync(params, ef, loss, grads):
         if mode == "per_param":
             grads = allreduce_per_param(grads, axis_name)
         else:
@@ -117,10 +149,23 @@ def make_ddp_train_step(loss_fn: Callable, mesh, *, axis_name: str = "data",
             params, grads)
         return new_params, ef, loss
 
-    in_specs = (P(), P(), P(axis_name))
-    out_specs = (P(), P(), P())
-    mapped = jax.shard_map(step, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)
+    def step(params, ef, batch):
+        (loss, metrics), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(params, batch)
+        return sync(params, ef, loss, grads)
+
+    def stateful_step(params, state, ef, batch):
+        (loss, (metrics, state)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(params, state, batch)
+        params, ef, loss = sync(params, ef, loss, grads)
+        axes = axis_name if isinstance(axis_name, tuple) else (axis_name,)
+        n = math.prod(mesh.shape[a] for a in axes)
+        return params, broadcast_from_first(state, axis_name, n), ef, loss
+
+    k = 3 if stateful else 2          # replicated inputs before the batch
+    mapped = jax.shard_map(stateful_step if stateful else step, mesh=mesh,
+                           in_specs=(P(),) * k + (P(axis_name),),
+                           out_specs=(P(),) * (k + 1), check_vma=False)
     return jax.jit(mapped)
 
 

@@ -63,6 +63,29 @@ class TestSyntheticLines:
         assert len(ops) == 1
 
 
+    @pytest.mark.parametrize("payload,nbytes", [
+        ("f32[9600]{0:T(1024)S(1)}", 9600 * 4),
+        ("u32[]{:S(2)}", 4),
+    ])
+    def test_tpu_async_collective_permute_start(self, payload, nbytes):
+        """A TPU's collective-permute-start returns (operand, result,
+        u32[], u32[]): the two context scalars are not the payload, and
+        a u32[] payload is still counted."""
+        done = payload.split("{")[0]
+        hlo = (f"%collective-permute-start = ({payload}, {payload}, "
+               "u32[]{:S(2)}, u32[]{:S(2)}) "
+               "collective-permute-start(%get-tuple-element.3), channel_id=1, "
+               "source_target_pairs={{0,1}}, metadata={op_name="
+               "\"jit(f)/shard_map/ppermute\"}\n"
+               f"%collective-permute-done = {done} "
+               "collective-permute-done(%collective-permute-start)")
+        (op,) = parse_hlo_collectives(hlo)
+        assert op.kind == "collective-permute"
+        assert op.source_target_pairs == [(0, 1)]
+        assert op.payload_bytes == nbytes
+        assert op.operand_names == ["get-tuple-element.3"]
+
+
 class TestHardening:
     """Malformed attributes raise (with the op text); the channel /
     global-ids / operand attributes round-trip."""

@@ -74,3 +74,18 @@ class TestInterceptor:
         s = icpt.summary()
         assert "AllReduce" in s and "AllGather" in s and "SendRecv" in s
         assert s["AllReduce"]["calls"] >= 1
+
+    def test_pbroadcast_recorded_as_broadcast(self, mesh8):
+        """jax.lax.pbroadcast (XLA's collective-broadcast) is hooked; only
+        traced here, as XLA:CPU cannot lower it."""
+        f = jax.jit(jax.shard_map(
+            lambda x: jax.lax.pbroadcast(x, ("data",), source=0),
+            mesh=mesh8, in_specs=P("data"), out_specs=P("data"),
+            check_vma=False))
+        with CollectiveInterceptor(mesh=mesh8) as icpt:
+            f.trace(jnp.ones((8, 16)))
+        (ev,) = icpt.events
+        assert (ev.primitive, ev.nccl_name) == ("pbroadcast", "Broadcast")
+        assert ev.axis_size == 4 and ev.payload_bytes == 2 * 16 * 4
+        assert icpt.summary() == {"Broadcast": {"calls": 1,
+                                                "payload_bytes": 128}}

@@ -206,8 +206,26 @@ class TestCallSites:
                 lower["end_ns"] - lower["start_ns"]) / 1e9
             assert cap.compile_seconds == (
                 comp["end_ns"] - comp["start_ns"]) / 1e9
-        # no counter but the served job's step count
-        assert all(r["counts"] is None for r in recs)
+        # no counter on the captures; each view counts the distinct shapes
+        # it decomposed and the edges it placed
+        from repro.core.comm_matrix import schedule_edge_arrays
+        batch = sess.view().schedule_batch()
+        edges = sum(schedule_edge_arrays(s)[0].size for s in batch.schedules)
+        assert batch.num_distinct > 0 and edges > 0
+        for r in recs:
+            if r["name"] == "view.schedule":
+                assert set(r["counts"]) == {"view.shapes"}
+            elif r["name"] in ("view.matrix", "view.per_primitive"):
+                assert set(r["counts"]) == {"view.edges"}
+            else:
+                assert r["counts"] is None, r["name"]
+        # report()'s own views, the first to close: the whole session's
+        matrix = by_name(recs, "view.matrix")[0]
+        sched = [r for r in recs if r["parent"] == matrix["id"]]
+        assert sched[0]["counts"] == {"view.shapes": batch.num_distinct}
+        assert matrix["counts"] == {"view.edges": edges}
+        assert by_name(recs, "view.per_primitive")[0]["counts"] == {
+            "view.edges": edges}
         lowers = {r["id"] for r in by_name(recs, "capture.lower")}
         under = {r["name"] for r in recs if r["parent"] in lowers}
         assert "/jax/core/compile/jaxpr_trace_duration" in under

@@ -24,6 +24,8 @@ GRANITE_HEADS, GRANITE_DH, PREFILL_BATCH = 32, 64, 4
 # ResNet-18 (200 classes) parameters x 4 B in two 25 MiB gradient buckets,
 # plus the 4-byte loss pmean
 DDP_PSUM_BYTES = 11_269_640 * 4 + 4
+# the published layout (7x7 stem, BatchNorm on the shortcuts too)
+DDP_BN_PSUM_BYTES = 11_279_112 * 4 + 4
 
 
 @pytest.fixture(scope="module")
@@ -111,6 +113,48 @@ def test_resnet18_ddp_allreduce_bytes(topo):
                  if ev.primitive == "psum")
     assert compiled == traced == DDP_PSUM_BYTES
     assert cap.memory_stats["total_bytes"] > 0 and cap.cost["flops"] > 0
+
+
+def test_resnet18_bn_ddp_collectives(topo):
+    """The published ResNet-18 (BatchNorm) under DDP as the benchmark runs
+    it, 64 64x64 images per chip at float32 HIGHEST: the TPU compiler emits
+    the gradients' all-reduce, 45,116,452 B, and, for the buffer broadcast
+    from chip 0, three asynchronous collective-permute-starts of the
+    38,400-byte buffer along the chain 0 -> 1 -> 2 -> 3 (XLA:TPU has no
+    collective-broadcast); compiled equal to traced."""
+    from repro.core import MonitorSession
+    from repro.models.resnet import ResNet18
+    from repro.train import ddp
+
+    mesh = Mesh(np.array(topo.devices), ("data",),
+                axis_types=(AxisType.Auto,))
+    model = ResNet18(200, published=True, precision="highest")
+    step = ddp.make_ddp_train_step(model.stateful_loss_fn, mesh,
+                                   bucket_mb=25.0, stateful=True)
+    repl, split = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    shapes = lambda t, s: jax.tree.map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s), t)
+    params = shapes(model.shapes(), repl)
+    state = shapes(jax.eval_shape(model.init_state), repl)
+    batch = {"images": jax.ShapeDtypeStruct((256, 64, 64, 3), jnp.float32,
+                                            sharding=split),
+             "labels": jax.ShapeDtypeStruct((256,), jnp.int32,
+                                            sharding=split)}
+    sess = MonitorSession(mesh=mesh, name="ddp-resnet18-bn")
+    cap = sess.capture(step, params, state, params, batch)
+    assert "collective-permute-start" in cap.hlo_text
+    assert "collective-broadcast" not in cap.hlo_text
+    permutes = [op for op in cap.ops if op.kind == "collective-permute"]
+    assert sorted(op.source_target_pairs[0] for op in permutes) == [
+        (0, 1), (1, 2), (2, 3)]
+    assert [op.payload_bytes for op in permutes] == [38_400] * 3
+    compiled = sum(op.payload_bytes * op.weight for op in cap.ops
+                   if op.kind == "all-reduce")
+    traced = sum(ev.payload_bytes for ev in cap.traced
+                 if ev.primitive == "psum")
+    assert compiled == traced == DDP_BN_PSUM_BYTES
+    assert sum(ev.payload_bytes for ev in cap.traced
+               if ev.primitive == "ppermute") == 3 * 38_400
 
 
 def test_served_decode_updates_the_cache_in_place(topo):
