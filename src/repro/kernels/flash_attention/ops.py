@@ -18,13 +18,10 @@ def attend(q, k, v, *, causal: bool = True, window: int = 0,
     """
     backend = force or ("pallas" if jax.default_backend() == "tpu" else "xla")
     if backend in ("pallas", "pallas_interpret"):
-        from .kernel import flash_attention
-        sq, skv = q.shape[1], k.shape[1]
-        bq = 128 if sq % 128 == 0 else sq
-        bk = 512 if skv % 512 == 0 else (128 if skv % 128 == 0 else skv)
+        from .kernel import flash_attention    # blocks chosen from the shapes
         return flash_attention(
-            q, k, v, causal=causal, window=window, block_q=bq, block_k=bk,
-            q_offset=q_offset, interpret=(backend == "pallas_interpret"))
+            q, k, v, causal=causal, window=window, q_offset=q_offset,
+            interpret=(backend == "pallas_interpret"))
     if backend == "xla":
         from repro.models.attention import chunked_attention
         return chunked_attention(q, k, v, causal=causal, window=window,

@@ -3,7 +3,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels.flash_attention.kernel import flash_attention
+from repro.kernels.flash_attention.kernel import (
+    FIRST, LAST, MASKED, VMEM_BUDGET, _block_pairs, choose_blocks,
+    flash_attention, vmem_bytes)
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.rglru.ops import rglru_scan
 from repro.kernels.rglru.ref import rglru_ref
@@ -60,6 +62,87 @@ class TestFlashAttention:
         o2 = flash_attention(q, k, v, block_q=128, block_k=256,
                              interpret=True)
         assert jnp.max(jnp.abs(o1 - o2)) < 2e-5
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("sq,skv,window,q_offset,block_q,block_k", [
+        # causal: pairs wholly inside, straddling and skipped, Bq != Bk
+        (512, 512, 0, 0, 128, 256),
+        # prefill continuation: q_offset > 0, sq < skv
+        (256, 512, 0, 256, 128, 128),
+        # a window whose edge falls inside a block
+        (512, 512, 200, 0, 128, 256),
+        # ... one key inside a block below the diagonal
+        (512, 512, 255, 0, 128, 128),
+        # the chooser's own blocks at prefill-b4's and decode-b32's prompts
+        (3584, 3584, 0, 0, None, None),
+        (512, 512, 0, 0, None, None),
+    ])
+    def test_block_pairs_match_ref(self, dtype, sq, skv, window, q_offset,
+                                   block_q, block_k):
+        ks = jax.random.split(jax.random.PRNGKey(sq + window + q_offset), 3)
+        q = rand(ks[0], (1, sq, 2, 64), dtype)
+        k = rand(ks[1], (1, skv, 1, 64), dtype)
+        v = rand(ks[2], (1, skv, 1, 64), dtype)
+        out = flash_attention(q, k, v, window=window, q_offset=q_offset,
+                              block_q=block_q, block_k=block_k,
+                              interpret=True)
+        ref = attention_ref(q, k, v, window=window, q_offset=q_offset)
+        tol = 2e-5 if dtype == jnp.float32 else 2e-2
+        assert out.dtype == dtype
+        assert jnp.max(jnp.abs(out.astype(jnp.float32)
+                               - ref.astype(jnp.float32))) < tol
+
+    def test_causal_grid_lists_only_needed_pairs(self):
+        """prefill-b4's 3,584 positions in 512-blocks: 28 of the 49 pairs
+        take a grid step, and only the 7 on the diagonal build a mask."""
+        qb, kb, flags = _block_pairs(7, 7, 512, 512, True, 0, 0)
+        assert len(qb) == 28 and (kb <= qb).all()
+        assert ((flags & MASKED) != 0).sum() == 7
+        assert (kb[(flags & MASKED) != 0] == qb[(flags & MASKED) != 0]).all()
+        assert (kb[(flags & FIRST) != 0] == 0).all()
+        assert (kb[(flags & LAST) != 0] == range(7)).all()
+
+    @pytest.mark.parametrize("sq,skv,dh,dtype,want", [
+        (3584, 3584, 64, jnp.bfloat16, (512, 512)),   # prefill-b4
+        (512, 512, 64, jnp.bfloat16, (512, 512)),     # decode-b32's prefill
+        (384, 384, 64, jnp.bfloat16, (128, 128)),
+        (1024, 4096, 128, jnp.float32, None),
+        (512, 8192, 256, jnp.float32, None),
+    ])
+    def test_chosen_blocks_divide_and_fit(self, sq, skv, dh, dtype, want):
+        bq, bk = choose_blocks(sq, skv, dh, dtype)
+        assert sq % bq == 0 and skv % bk == 0
+        assert vmem_bytes(bq, bk, dh, jnp.dtype(dtype).itemsize) <= VMEM_BUDGET
+        assert want is None or (bq, bk) == want
+
+    def test_indivisible_length_takes_whole_sequence(self):
+        assert choose_blocks(1000, 1000, 64, jnp.float32) == (1000, 1000)
+        ks = jax.random.split(RNG, 3)
+        q, k, v = (rand(kk, (1, 1000, 2, 64)) for kk in ks)
+        out = flash_attention(q, k, v, interpret=True)
+        assert jnp.max(jnp.abs(out - attention_ref(q, k, v))) < 2e-5
+
+    @pytest.mark.parametrize("sq,block_q,want", [
+        (512, None, 128),     # the chooser's 512-row blocks
+        (1000, None, 1000),   # no 128 divides: one whole-sequence chunk
+        (256, 64, 64),        # explicit blocks under 128 keep theirs
+    ])
+    def test_backward_chunks_at_most_128_rows(self, monkeypatch, sq,
+                                              block_q, want):
+        """The backward, the chunked XLA path's VJP, keeps q chunks of at
+        most 128 rows whatever the forward's blocks."""
+        import repro.models.attention as attention
+        chunks, chunked = [], attention.chunked_attention
+
+        def spy(*args, q_chunk, **kw):
+            chunks.append(q_chunk)
+            return chunked(*args, q_chunk=q_chunk, **kw)
+
+        monkeypatch.setattr(attention, "chunked_attention", spy)
+        q = rand(RNG, (1, sq, 1, 8))
+        jax.make_jaxpr(jax.grad(lambda q: flash_attention(
+            q, q, q, block_q=block_q, interpret=True).sum()))(q)
+        assert chunks == [want]
 
     @pytest.mark.parametrize("sq,h,kvh,window,q_offset", [
         (256, 4, 2, 0, 0),       # GQA causal, two q blocks

@@ -55,7 +55,8 @@ def _attend(q, k, v):
     return ops.attend(q, k, v, force="pallas")
 
 
-@pytest.mark.parametrize("seq", [512, 1000])   # 1000: whole-sequence blocks
+# 3584: prefill-b4's prompts; 1000: whole-sequence blocks
+@pytest.mark.parametrize("seq", [512, 1000, 3584])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_flash_attention_prefill_compiles(one_chip, seq, dtype):
     x = jax.ShapeDtypeStruct((PREFILL_BATCH, seq, GRANITE_HEADS, GRANITE_DH),
@@ -66,6 +67,14 @@ def test_flash_attention_prefill_compiles(one_chip, seq, dtype):
     assert compiled.memory_analysis().output_size_in_bytes >= (
         PREFILL_BATCH * seq * GRANITE_HEADS * GRANITE_DH
         * jnp.dtype(dtype).itemsize)
+
+
+def test_flash_attention_decode_prompt_compiles(one_chip):
+    """decode-b32's prefill: 32 prompts of 512, one 512 x 512 block."""
+    x = jax.ShapeDtypeStruct((32, 512, GRANITE_HEADS, GRANITE_DH),
+                             jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(_attend).lower(x, x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_flash_attention_gradient_compiles(one_chip):
