@@ -23,8 +23,8 @@ import traceback
 
 from benchmarks import common
 
-BENCHES = ("table1", "table2", "table3", "fig3", "links", "matrix",
-           "schedule", "roofline", "scale", "trace")
+BENCHES = ("table1", "table2", "table3", "fig3", "links", "roofline",
+           "scale", "trace")
 
 _MODS = {
     "table1": "benchmarks.table1_collective_bytes",
@@ -32,8 +32,6 @@ _MODS = {
     "table3": "benchmarks.table3_resnet_bucketing",
     "fig3": "benchmarks.fig3_per_primitive",
     "links": "benchmarks.link_utilization",
-    "matrix": "benchmarks.matrix_build",
-    "schedule": "benchmarks.schedule_eval",
     "roofline": "benchmarks.roofline_table",
     "scale": "benchmarks.scale_curve",
     "trace": "benchmarks.trace_ingest",

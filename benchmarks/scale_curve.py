@@ -29,11 +29,60 @@ import types
 import numpy as np
 
 from benchmarks.common import ARTIFACTS, emit
-from benchmarks.matrix_build import _time, synthetic_ops
 from repro import scale
 from repro.core import comm_matrix
 from repro.core.events import CollectiveOp, Shape
 from repro.core.reporter import format_table
+
+KINDS = ("all-reduce", "all-gather", "reduce-scatter",
+         "collective-broadcast", "all-to-all", "collective-permute")
+
+
+def synthetic_ops(num_ops: int, num_devices: int,
+                  seed: int = 0) -> list[CollectiveOp]:
+    """A randomized op stream shaped like a long monitored session: mixed
+    kinds, groups spanning large slices of the mesh, loop-trip weights."""
+    rng = np.random.default_rng(seed)
+    ops = []
+    for i in range(num_ops):
+        kind = KINDS[int(rng.integers(len(KINDS)))]
+        elems = int(rng.integers(1, 1 << 14))
+        weight = float(rng.integers(1, 65))
+        if kind == "collective-permute":
+            perm = rng.permutation(num_devices)
+            pairs = [(int(perm[j]), int(perm[(j + 1) % len(perm)]))
+                     for j in range(len(perm))]
+            ops.append(CollectiveOp(
+                kind=kind, name=f"op{i}",
+                result_shapes=[Shape("f32", (elems,))],
+                replica_groups=[], source_target_pairs=pairs,
+                weight=weight))
+            continue
+        # partition the mesh into equal groups of a random power-of-two
+        # size; all-to-all is quadratic in group size (n*(n-1) edges per
+        # group), so it sweeps small groups while the ring/tree kinds span
+        # up to the whole mesh
+        sizes = ((4, 8, 16) if kind == "all-to-all"
+                 else (8, 16, 64, num_devices))
+        gsize = int(rng.choice([s for s in sizes if s <= num_devices]))
+        devs = rng.permutation(num_devices)
+        groups = [sorted(int(d) for d in devs[k:k + gsize])
+                  for k in range(0, num_devices, gsize)]
+        ops.append(CollectiveOp(
+            kind=kind, name=f"op{i}",
+            result_shapes=[Shape("f32", (elems,))],
+            replica_groups=groups, weight=weight))
+    return ops
+
+
+def _time(fn, repeats: int = 3) -> float:
+    best = float("inf")
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        fn()
+        best = min(best, time.perf_counter() - t0)
+    return best
+
 
 # tracemalloc bound for the 16k-device sparse build + projection: far under
 # the ~2.1 GiB the dense (16385)^2 float64 matrix alone would need

@@ -588,8 +588,8 @@ def build_parser() -> argparse.ArgumentParser:
 
     p = sub.add_parser("bench", help="paper-table benchmark suite")
     p.add_argument("names", nargs="*",
-                   help="table1 table2 table3 fig3 links matrix schedule "
-                        "roofline scale trace (default: all)")
+                   help="table1 table2 table3 fig3 links roofline scale "
+                        "trace (default: all)")
     p.add_argument("--devices", type=int, default=8)
     p.set_defaults(func=_cmd_bench)
 

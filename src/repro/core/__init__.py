@@ -6,7 +6,6 @@ from .interceptor import CollectiveInterceptor, intercept, traced_summary
 from .hlo_parser import parse_hlo_collectives, summarize, total_wire_bytes
 from .comm_matrix import (LinkUtilization, add_host_transfers,
                           link_utilization_for_ops, matrix_for_ops,
-                          matrix_for_ops_reference, op_edge_arrays, op_edges,
                           per_primitive_matrices, project_links)
 # NOTE: the decompose() function itself is NOT re-exported at package
 # level -- binding the name here would shadow the repro.core.decompose
@@ -38,8 +37,7 @@ __all__ = [
     "jax_shape",
     "CollectiveInterceptor", "intercept", "traced_summary",
     "parse_hlo_collectives", "summarize", "total_wire_bytes",
-    "matrix_for_ops", "matrix_for_ops_reference", "op_edges",
-    "op_edge_arrays", "per_primitive_matrices", "add_host_transfers",
+    "matrix_for_ops", "per_primitive_matrices", "add_host_transfers",
     "LinkUtilization", "project_links", "link_utilization_for_ops",
     "CollectiveSchedule", "CommPhase", "HierarchicalFallbackWarning",
     "ALGORITHMS", "validate_algorithm",

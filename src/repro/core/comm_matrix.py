@@ -29,16 +29,15 @@ DCN uplinks of a :class:`~repro.core.topology.MeshTopology`, yielding
 per-link byte counts, the bottleneck link, and a contention-aware time
 bound.
 
-**Vectorized accumulation.**  :func:`matrix_for_ops` renders each op's
-schedule as numpy COO arrays (:func:`op_edge_arrays`; the schedule batches
-same-size replica groups into shared phases) and batches them into edge
-buffers flushed with a single ``np.add.at`` per flush, so a session with
-thousands of weighted ops on a large mesh builds its matrix without a
-per-edge Python loop.  The retired pre-schedule placement survives only as
-:func:`matrix_for_ops_reference` -- the legacy per-kind, per-edge oracle
-that pins schedule-derived matrices equal to the old loop on single-axis
-groups (``benchmarks/matrix_build.py`` also measures the COO path against
-it).
+**One path.**  :func:`matrix_for_ops` decomposes the op stream once per
+distinct shape (:func:`~repro.core.decompose.schedules_for_ops`) and hands
+the aligned schedules to :func:`matrix_for_schedules`, which renders each
+distinct schedule as numpy COO arrays (:func:`schedule_edge_arrays`; the
+schedule batches same-size replica groups into shared phases) and adds
+each op's weighted edges with one ``np.add.at``, in op order.  Sparse
+matrices at or below ``SPARSE_DEVICE_THRESHOLD`` devices are that dense
+build converted exactly; above it a bounded-memory
+:class:`~repro.core.sparse.SparseAccumulator` takes the same edges.
 """
 from __future__ import annotations
 
@@ -50,124 +49,13 @@ import numpy as np
 from .events import CollectiveOp, HostTransfer
 from . import cost_models, decompose as decompose_mod
 from .decompose import HierarchicalFallbackWarning, decompose  # noqa: F401
-from .sparse import SparseAccumulator, SparseCommMatrix, is_sparse
+from .sparse import (SPARSE_DEVICE_THRESHOLD, SparseAccumulator,
+                     SparseCommMatrix, from_dense, is_sparse)
 from .topology import DCN_FABRIC, Link, MeshTopology
 
 
 # ---------------------------------------------------------------------------
-# Scalar edge placement: the schedule rendered as (src, dst, bytes) tuples.
-# ---------------------------------------------------------------------------
-def _ring_edges(group, per_rank: float) -> list[tuple[int, int, float]]:
-    """Bidirectional ring: each member streams half its per-rank bytes to
-    each ring neighbour (the torus ring algorithm uses both directions of
-    the axis links -- the bandwidth ``ring_bw_per_chip`` credits).  On a
-    2-member ring both halves reach the same peer and accumulate."""
-    group = list(group)
-    n = len(group)
-    half = 0.5 * per_rank
-    out: list[tuple[int, int, float]] = []
-    for i in range(n):
-        out.append((group[i], group[(i + 1) % n], half))
-        out.append((group[i], group[(i - 1) % n], half))
-    return out
-
-
-def _tree_placement(group, kind: str,
-                    s: float) -> list[tuple[int, int, float]]:
-    """Per-edge bytes on the implicit binary tree (heap layout), resolved
-    from the shared :func:`repro.core.decompose.tree_edge_profile`."""
-    group = list(group)
-    n = len(group)
-    up, down = decompose_mod.tree_edge_profile(kind, s, n)
-    edges: list[tuple[int, int, float]] = []
-    for i in range(1, n):
-        parent, child = group[(i - 1) // 2], group[i]
-        if up[i - 1]:
-            edges.append((child, parent, float(up[i - 1])))
-        if down[i - 1]:
-            edges.append((parent, child, float(down[i - 1])))
-    return edges
-
-
-def _phase_edges(ph) -> list[tuple[int, int, float]]:
-    """Scalar edges of ONE schedule phase.
-
-    Vector phases (``bytes_per_rank`` is an ndarray, see
-    :class:`~repro.core.decompose.CommPhase`) place per-position amounts:
-    ring members stream half their own per-rank bytes to each neighbour,
-    a2a members send ``per_rank / (n-1)`` to each peer, and ``pair_bytes``
-    overrides the uniform per-pair payload of ``structure="pairs"``.
-    """
-    if ph.structure == "pairs":
-        if ph.pairs is None:
-            return []
-        if ph.pair_bytes is not None:
-            return [(int(a), int(b), float(v))
-                    for (a, b), v in zip(ph.pairs.tolist(),
-                                         ph.pair_bytes.tolist())]
-        return [(int(a), int(b), ph.payload) for a, b in ph.pairs]
-    if ph.groups is None:
-        return []
-    G = np.atleast_2d(ph.groups)
-    B = ph.byte_matrix()
-    out: list[tuple[int, int, float]] = []
-    if ph.structure == "ring":
-        if B is not None:
-            for row, brow in zip(G, B):
-                members = row.tolist()
-                n = len(members)
-                for i, per in enumerate(brow.tolist()):
-                    out.append((members[i], members[(i + 1) % n],
-                                0.5 * per))
-                    out.append((members[i], members[(i - 1) % n],
-                                0.5 * per))
-        else:
-            for row in G:
-                out += _ring_edges(row.tolist(), ph.bytes_per_rank)
-    elif ph.structure == "tree":
-        for row in G:
-            out += _tree_placement(row.tolist(), ph.kind, ph.payload)
-    elif ph.structure == "a2a":
-        n = G.shape[1]
-        if B is not None:
-            for row, brow in zip(G, B):
-                members = row.tolist()
-                per_peer = (brow / (n - 1)).tolist()
-                out += [(a, b, per_peer[i])
-                        for i, a in enumerate(members)
-                        for b in members if a != b]
-        else:
-            block = ph.payload / (n * n)
-            for row in G:
-                members = row.tolist()
-                out += [(a, b, block) for a in members for b in members
-                        if a != b]
-    return out
-
-
-def op_edges(op: CollectiveOp, algorithm: str = "ring",
-             topo: Optional[MeshTopology] = None) -> list[tuple[int, int, float]]:
-    """``(src, dst, bytes)`` edges for ONE execution of ``op`` (weight not
-    applied) -- the scalar rendering of the op's decomposition schedule.
-
-    Production matrix building goes through the vectorized
-    :func:`op_edge_arrays`; both walk the same
-    :func:`~repro.core.decompose.decompose` output, and a property test
-    pins their aggregate traffic equal.  A hierarchical request for a
-    cross-pod group the shared predicate cannot decompose emits a
-    :class:`HierarchicalFallbackWarning` and places flat ring edges
-    instead (silently degenerating is exactly the matrix/model mismatch
-    this module exists to expose).
-    """
-    sched = decompose_mod.cached_decompose(op, algorithm, topo)
-    edges: list[tuple[int, int, float]] = []
-    for ph in sched.phases:
-        edges += _phase_edges(ph)
-    return edges
-
-
-# ---------------------------------------------------------------------------
-# Vectorized edge generation: numpy COO arrays instead of per-edge tuples.
+# Edge placement: each schedule phase rendered as numpy COO arrays.
 # ---------------------------------------------------------------------------
 _EMPTY_EDGES = (np.empty(0, dtype=np.intp), np.empty(0, dtype=np.intp),
                 np.empty(0, dtype=np.float64))
@@ -200,11 +88,12 @@ def _ring_neighbor_idx(n: int) -> np.ndarray:
 def _ring_edges_arr(rings, per_rank):
     """Bidirectional ring edges for a batch of rings (one per row).
 
-    The array form of :func:`_ring_edges`: each member streams half its
-    per-rank bytes to each neighbour (cached neighbour-index gather along
-    the row axis); on a 2-member ring both halves land on the same peer
-    and accumulate.  ``per_rank`` may be an ndarray (1-D positional or
-    ``(k, n)``): each member then streams half its *own* amount.
+    Each member streams half its per-rank bytes to each ring neighbour
+    (the torus ring algorithm uses both directions of the axis links --
+    the bandwidth ``ring_bw_per_chip`` credits); on a 2-member ring both
+    halves land on the same peer and accumulate.  ``per_rank`` may be an
+    ndarray (1-D positional or ``(k, n)``): each member then streams half
+    its *own* amount.
     """
     r = np.asarray(rings, dtype=np.intp)
     if r.ndim == 1:
@@ -219,10 +108,11 @@ def _ring_edges_arr(rings, per_rank):
 
 
 def _tree_edges_arr(groups, kind: str, s: float):
-    """Array form of :func:`_tree_placement` (same heap-layout tree) for a
-    batch of same-size groups (one per row) -- the per-edge byte profile
-    depends only on the tree *position*, so it is computed once per column
-    and tiled over the batch."""
+    """Per-edge bytes on the implicit binary tree (heap layout) for a
+    batch of same-size groups (one per row), from the shared
+    :func:`repro.core.decompose.tree_edge_profile` -- the profile depends
+    only on the tree *position*, so it is computed once per column and
+    tiled over the batch."""
     G = np.asarray(groups, dtype=np.intp)
     if G.ndim == 1:
         G = G[None, :]
@@ -258,8 +148,14 @@ def _a2a_edges_arr(groups, block: float, per_src=None):
 
 
 def _phase_edge_arrays(ph):
-    """COO arrays of ONE schedule phase (the vectorized
-    :func:`_phase_edges`)."""
+    """COO arrays of ONE schedule phase.
+
+    Vector phases (``bytes_per_rank`` is an ndarray, see
+    :class:`~repro.core.decompose.CommPhase`) place per-position amounts:
+    ring members stream half their own per-rank bytes to each neighbour,
+    a2a members send ``per_rank / (n-1)`` to each peer, and ``pair_bytes``
+    overrides the uniform per-pair payload of ``structure="pairs"``.
+    """
     if ph.structure == "pairs":
         if ph.pairs is None:
             return _EMPTY_EDGES
@@ -284,31 +180,12 @@ def _phase_edge_arrays(ph):
 
 
 def schedule_edge_arrays(sched):
-    """``(src, dst, bytes)`` COO arrays of one whole schedule."""
+    """``(src, dst, bytes)`` COO arrays of one whole schedule (one
+    execution; the op weight is not applied) -- the only way a schedule
+    becomes edges."""
     if not sched.phases:
         return _EMPTY_EDGES
     return _concat_edges([_phase_edge_arrays(ph) for ph in sched.phases])
-
-
-def op_edge_arrays(op: CollectiveOp, algorithm: str = "ring",
-                   topo: Optional[MeshTopology] = None):
-    """``(src, dst, bytes)`` numpy arrays for ONE execution of ``op``.
-
-    The vectorized twin of :func:`op_edges` -- identical aggregate traffic
-    (property-tested), produced as COO arrays so :func:`matrix_for_ops`
-    accumulates them without a per-edge Python loop.  The schedule already
-    batches same-size replica groups into shared phases (an op with 32
-    groups of 8 costs the same handful of numpy calls as one group would),
-    and emits the same :class:`HierarchicalFallbackWarning` in the same
-    refusal case.
-    """
-    return schedule_edge_arrays(
-        decompose_mod.cached_decompose(op, algorithm, topo))
-
-
-# flush threshold for the batched COO accumulation: large enough to amortize
-# np.add.at, small enough to keep the edge buffers cache-resident
-_FLUSH_EDGES = 32768
 
 
 def matrix_for_ops(
@@ -324,224 +201,67 @@ def matrix_for_ops(
     ``topo`` enables topology-faithful placement (per-axis ring phases for
     multi-axis groups, the hierarchical algorithm's pod decomposition);
     without it every schedule degenerates to flattened rings, matching
-    ``wire_bytes_per_rank(..., pods=1)``.
-
-    Accumulation is vectorized: per-op COO edge arrays
-    (:func:`op_edge_arrays`, execution weights applied per op) are batched
-    into buffers and flushed with one ``np.add.at`` per
-    ``_FLUSH_EDGES``-sized batch -- see :func:`matrix_for_ops_reference`
-    for the legacy oracle this is property-tested against.
+    ``wire_bytes_per_rank(..., pods=1)``.  A hierarchical request for a
+    cross-pod group the shared predicate cannot decompose emits a
+    :class:`HierarchicalFallbackWarning` and places flat ring edges.
 
     ``sparse=True`` returns a :class:`~repro.core.sparse.SparseCommMatrix`
-    instead of the dense array -- element-exact (property-tested), built
-    without ever allocating ``(d+1)^2`` floats, which is what makes
-    fleet-scale device counts (``sweep --scale-curve``, 16k devices)
-    tractable.
+    with the same entries (see :func:`matrix_for_schedules`).
     """
     cost_models.validate_algorithm(algorithm)
     kept = [op for op in ops if kinds is None or op.kind in kinds]
     scheds = decompose_mod.schedules_for_ops(kept, algorithm, topo,
                                              warn=True)
-    return _accumulate_edges(_edge_pairs(kept, scheds, None, {}),
-                             num_devices, sparse=sparse)
+    return matrix_for_schedules(kept, scheds, num_devices, sparse=sparse)
 
 
-def _edge_pairs(ops, schedules, kinds, edge_cache: dict):
-    """``(op, (src, dst, val))`` pairs in op order, with edge arrays built
-    once per *distinct* schedule object (``id``-keyed, which the deduped
-    ``schedules_for_ops`` output makes meaningful).  Accumulation stays
-    per-op so the float addition order -- and hence the matrix, bitwise --
-    is identical to the uncached path."""
+def matrix_for_schedules(ops, schedules, num_devices: int,
+                         kinds: Optional[set[str]] = None,
+                         sparse: bool = False,
+                         edge_cache: Optional[dict] = None):
+    """Bytes-sent matrix from the ops' schedules (aligned with ``ops``).
+
+    Edge arrays are rendered once per *distinct* schedule object, into
+    ``edge_cache`` (``{id(schedule): edge arrays}``; pass a dict that
+    outlives the call to share renders across calls, as a
+    :class:`~repro.core.views.CommView` does for its matrix and its
+    per-primitive slices).  Each op's weighted edges are then added with
+    one ``np.add.at``, in op order.
+
+    ``sparse=True`` returns a :class:`~repro.core.sparse.SparseCommMatrix`:
+    at or below ``SPARSE_DEVICE_THRESHOLD`` devices the dense build,
+    converted exactly; above it a bounded-memory accumulation that never
+    allocates ``(d+1)^2`` floats, which is what makes fleet-scale device
+    counts (``sweep --scale-curve``, 16k devices) tractable.
+    """
+    edge_cache = {} if edge_cache is None else edge_cache
+    dense = not sparse or num_devices <= SPARSE_DEVICE_THRESHOLD
+    if dense:
+        mat = np.zeros((num_devices + 1, num_devices + 1), dtype=np.float64)
+    else:
+        acc = SparseAccumulator(num_devices)
     for op, sched in zip(ops, schedules):
         if kinds is not None and op.kind not in kinds:
             continue
-        e = edge_cache.get(id(sched))
-        if e is None:
-            e = edge_cache[id(sched)] = schedule_edge_arrays(sched)
-        yield op, e
-
-
-def matrix_for_schedules(
-    ops, schedules, num_devices: int,
-    kinds: Optional[set[str]] = None,
-    sparse: bool = False,
-):
-    """Bytes-sent matrix from pre-built schedules (aligned with ``ops``).
-
-    The entry point for callers that already hold the ops' decomposition
-    schedules (e.g. a :class:`~repro.core.views.CommView`'s memoized IR):
-    identical accumulation to :func:`matrix_for_ops` without re-running
-    :func:`~repro.core.decompose.decompose` per op.  ``schedules`` may be
-    the plain aligned list or a :class:`~repro.core.decompose.
-    ScheduleBatch` -- the batch's persistent ``edge_cache`` then carries
-    rendered COO edge arrays across calls (the whole-matrix build and
-    every per-primitive slice of one view pay edge generation once per
-    distinct schedule).  ``sparse=True`` builds the COO
-    :class:`~repro.core.sparse.SparseCommMatrix` form.
-    """
-    if isinstance(schedules, decompose_mod.ScheduleBatch):
-        edge_cache = schedules.edge_cache
-        schedules = schedules.schedules
-    else:
-        edge_cache = {}
-    return _accumulate_edges(
-        _edge_pairs(ops, schedules, kinds, edge_cache),
-        num_devices, sparse=sparse)
-
-
-def _accumulate_edges_sparse(pairs, num_devices: int) -> SparseCommMatrix:
-    """Sparse twin of :func:`_accumulate_edges`: same per-op COO edges,
-    accumulated into a bounded-memory :class:`SparseAccumulator` -- no
-    ``(d+1)^2`` allocation anywhere on this path."""
-    acc = SparseAccumulator(num_devices)
-    for op, (src, dst, val) in pairs:
+        edges = edge_cache.get(id(sched))
+        if edges is None:
+            edges = edge_cache[id(sched)] = schedule_edge_arrays(sched)
+        src, dst, val = edges
         if src.size == 0:
             continue
-        w = getattr(op, "weight", 1.0)
         keep = (src < num_devices) & (dst < num_devices)
         if not keep.all():
             src, dst, val = src[keep], dst[keep], val[keep]
-        acc.add(src + 1, dst + 1, val * w if w != 1.0 else val)
-    return acc.build()
-
-
-def _accumulate_edges(pairs, num_devices: int,
-                      sparse: bool = False):
-    """Buffered COO accumulation over ``(op, (src, dst, val))`` pairs."""
-    if sparse:
-        return _accumulate_edges_sparse(pairs, num_devices)
-    mat = np.zeros((num_devices + 1, num_devices + 1), dtype=np.float64)
-    cap = _FLUSH_EDGES
-    buf_src = np.empty(cap, dtype=np.intp)
-    buf_dst = np.empty(cap, dtype=np.intp)
-    buf_val = np.empty(cap, dtype=np.float64)
-    pending = 0
-
-    def apply(src, dst, val):
-        keep = (src < num_devices) & (dst < num_devices)
-        if not keep.all():
-            src, dst, val = src[keep], dst[keep], val[keep]
-        np.add.at(mat, (src + 1, dst + 1), val)
-
-    def flush():
-        nonlocal pending
-        if pending:
-            apply(buf_src[:pending], buf_dst[:pending], buf_val[:pending])
-            pending = 0
-
-    for op, (src, dst, val) in pairs:
         w = getattr(op, "weight", 1.0)   # execution count (loop trip counts)
-        m = src.size
-        if m == 0:
-            continue
         if w != 1.0:
             val = val * w
-        if m >= cap:                     # oversized op: apply directly
-            flush()
-            apply(src, dst, val)
-            continue
-        if pending + m > cap:
-            flush()
-        buf_src[pending:pending + m] = src
-        buf_dst[pending:pending + m] = dst
-        buf_val[pending:pending + m] = val
-        pending += m
-    flush()
-    return mat
-
-
-# ---------------------------------------------------------------------------
-# Legacy oracle: the retired per-kind placement, kept ONLY to pin the
-# schedule-derived path against the old behavior on single-axis groups.
-# ---------------------------------------------------------------------------
-_TREE_KINDS = ("all-reduce", "all-gather", "reduce-scatter",
-               "collective-broadcast")
-
-
-def _legacy_hierarchical_placement(group, kind: str, s: float,
-                                   topo: MeshTopology):
-    """Pre-schedule hierarchical placement: flattened intra-pod rings +
-    cross-pod exchange (no per-axis decomposition)."""
-    dec = cost_models.hierarchical_decomposition(kind, list(group), topo)
-    if dec is None:
-        return None
-    p, m, subs = dec
-    phases = cost_models.hier_phases(kind)
-    edges: list[tuple[int, int, float]] = []
-    if m > 1:
-        intra_per_rank = phases * (m - 1) * s / m
-        for sub in subs:
-            edges.extend(_ring_edges(sub, intra_per_rank))
-    cross_per_rank = phases * (p - 1) * s / len(group)
-    for j in range(m):
-        ring = [subs[k][j] for k in range(p)]
-        edges.extend(_ring_edges(ring, cross_per_rank))
-    return edges
-
-
-def _legacy_op_edges(op: CollectiveOp, algorithm: str = "ring",
-                     topo: Optional[MeshTopology] = None):
-    """The pre-schedule scalar placement (flattened rings everywhere)."""
-    edges: list[tuple[int, int, float]] = []
-    if op.kind == "collective-permute":
-        nbytes = float(op.result_bytes) * op.num_groups
-        return [(src, dst, nbytes) for src, dst in op.source_target_pairs]
-    for group in op.replica_groups or [[]]:
-        n = len(group)
-        if n <= 1:
-            continue
-        s = float(op.payload_bytes)
-        if op.kind in ("all-to-all", "ragged-all-to-all"):
-            block = s / (n * n)
-            edges.extend((a, b, block)
-                         for a in group for b in group if a != b)
-            continue
-        if algorithm == "tree" and op.kind in _TREE_KINDS:
-            edges.extend(_tree_placement(group, op.kind, s))
-            continue
-        if algorithm == "hierarchical" and topo is not None:
-            placed = _legacy_hierarchical_placement(group, op.kind, s, topo)
-            if placed is not None:
-                edges.extend(placed)
-                continue
-            if op.kind in cost_models.HIERARCHICAL_KINDS \
-                    and topo.group_crosses_dcn(group):
-                decompose_mod.warn_fallback_once(
-                    op.kind, n,
-                    f"hierarchical {op.kind} over cross-pod group of {n} "
-                    "cannot decompose (uneven pod split); placing flat "
-                    "ring edges and billing the same fallback",
-                    stacklevel=1)
-        per_rank = cost_models.wire_bytes_per_rank(
-            op.kind, s, n, algorithm, pods=1)
-        edges.extend(_ring_edges(group, per_rank))
-    return edges
-
-
-def matrix_for_ops_reference(
-    ops: Iterable[CollectiveOp],
-    num_devices: int,
-    algorithm: str = "ring",
-    kinds: Optional[set[str]] = None,
-    topo: Optional[MeshTopology] = None,
-) -> np.ndarray:
-    """The pre-schedule builder: per-op, per-edge Python accumulation over
-    the legacy per-kind placement.  Kept as the readable oracle: on
-    single-axis replica groups (where per-axis decomposition does not
-    apply) schedule-derived matrices must equal this loop exactly -- the
-    property test pins that, and ``benchmarks/matrix_build.py`` measures
-    the COO-batched :func:`matrix_for_ops` against it.
-    """
-    cost_models.validate_algorithm(algorithm)
-    mat = np.zeros((num_devices + 1, num_devices + 1), dtype=np.float64)
-    for op in ops:
-        if kinds is not None and op.kind not in kinds:
-            continue
-        w = getattr(op, "weight", 1.0)
-        for src, dst, nbytes in _legacy_op_edges(op, algorithm, topo):
-            if src < num_devices and dst < num_devices:
-                mat[src + 1, dst + 1] += nbytes * w
-    return mat
+        if dense:
+            np.add.at(mat, (src + 1, dst + 1), val)
+        else:
+            acc.add(src + 1, dst + 1, val)
+    if not dense:
+        return acc.build()
+    return from_dense(mat) if sparse else mat
 
 
 def add_host_transfers(mat, transfers: Iterable[HostTransfer]):
@@ -726,7 +446,7 @@ def project_links(mat, topo: MeshTopology) -> LinkUtilization:
     :meth:`MeshTopology.route` (dimension-ordered wrap-aware torus routing,
     DCN uplink+downlink across pods) and its bytes charged to every hop.
     The matrices this module builds are schedule-derived
-    (:func:`op_edge_arrays` renders :func:`~repro.core.decompose.
+    (:func:`schedule_edge_arrays` renders :func:`~repro.core.decompose.
     decompose` output), so the projection IS the schedule's link view.
 
     Every routed hop must be one of the enumerated physical links -- in

@@ -250,17 +250,34 @@ def collective_time(op: CollectiveOp, topo: MeshTopology,
     return ici + dcn
 
 
-def total_time(ops: Iterable[CollectiveOp], topo: MeshTopology,
-               algorithm: str = "ring", *,
-               include_latency: bool = True) -> float:
-    """Serialized collective time (no overlap) -- upper bound / roofline term.
+def time_splits(schedules, topo: MeshTopology, *,
+                include_latency: bool = True) -> list[tuple[float, float]]:
+    """``(ici_s, dcn_s)`` of ONE execution per schedule (aligned with
+    ``schedules``): :meth:`~repro.core.decompose.CollectiveSchedule.
+    time_split`, run once per *distinct* schedule object (ops sharing a
+    signature share one, see :func:`~repro.core.decompose.
+    schedules_for_ops`)."""
+    memo: dict = {}
+    out = []
+    for sched in schedules:
+        split = memo.get(id(sched))
+        if split is None:
+            split = memo[id(sched)] = sched.time_split(
+                topo, include_latency=include_latency)
+        out.append(split)
+    return out
 
-    Execution-weighted: an op inside a while body contributes once per trip.
-    """
-    return float(sum(
-        collective_time(op, topo, algorithm,
-                        include_latency=include_latency)
-        * max(1.0, getattr(op, "weight", 1.0)) for op in ops))
+
+def weighted_time_split(ops, splits) -> tuple[float, float]:
+    """Execution-weighted per-tier sums of per-op ``splits`` (aligned with
+    ``ops``), added in op order.  An op inside a while body contributes
+    once per trip."""
+    ici = dcn = 0.0
+    for op, (i, d) in zip(ops, splits):
+        w = max(1.0, getattr(op, "weight", 1.0))
+        ici += i * w
+        dcn += d * w
+    return ici, dcn
 
 
 def total_time_split(ops: Iterable[CollectiveOp], topo: MeshTopology,
@@ -268,17 +285,23 @@ def total_time_split(ops: Iterable[CollectiveOp], topo: MeshTopology,
                      include_latency: bool = True) -> tuple[float, float]:
     """Execution-weighted per-tier serialized sums ``(ici_s, dcn_s)``.
 
-    ``total_time == sum(total_time_split)`` by construction; the overlap
-    roofline bound takes ``max`` of these instead of their sum (ICI and DCN
-    are independent fabrics, so their busy times can fully overlap).
-    Evaluated through the columnar :class:`~repro.core.decompose.
-    ScheduleBatch` (decompose once per distinct shape, per-tier sums as
-    array expressions) -- bitwise identical to the per-op loop it
-    replaced.
+    The overlap roofline bound takes ``max`` of these instead of their sum
+    (ICI and DCN are independent fabrics, so their busy times can fully
+    overlap).  Decomposes once per distinct op shape.
     """
-    batch = _dec.ScheduleBatch.from_ops(list(ops), algorithm, topo,
-                                        warn=False)
-    return batch.total_time_split(topo, include_latency=include_latency)
+    ops = list(ops)
+    scheds = _dec.schedules_for_ops(ops, algorithm, topo)
+    return weighted_time_split(
+        ops, time_splits(scheds, topo, include_latency=include_latency))
+
+
+def total_time(ops: Iterable[CollectiveOp], topo: MeshTopology,
+               algorithm: str = "ring", *,
+               include_latency: bool = True) -> float:
+    """Serialized collective time (no overlap) -- upper bound / roofline
+    term: the sum of :func:`total_time_split`'s two tiers."""
+    return sum(total_time_split(ops, topo, algorithm,
+                                include_latency=include_latency))
 
 
 def contention_time(ops: Iterable[CollectiveOp], topo: MeshTopology,

@@ -14,7 +14,7 @@ CollectiveOp` under one ``(algorithm, topology)`` binding into a
 Every consumer derives from the schedule instead of re-implementing
 algorithm knowledge:
 
-* **placement** -- ``comm_matrix.op_edges`` / ``op_edge_arrays`` place each
+* **placement** -- ``comm_matrix.schedule_edge_arrays`` places each
   phase's edges (ring / tree / all-to-all / explicit pairs);
 * **billing**  -- ``cost_models.wire_bytes_per_rank`` /
   ``device_send_bytes`` sum per-phase per-rank bytes;
@@ -1072,7 +1072,7 @@ def decompose(op: CollectiveOp, algorithm: str = "ring",
 
 
 # ----------------------------------------------------------------------------
-# Batched schedule evaluation: memoized decompose + columnar phase columns.
+# Memoized decompose: one decomposition per distinct op shape.
 #
 # ``decompose`` is pure in ``(op shape, algorithm, topology)`` -- it never
 # reads ``op.weight``, ``op.name`` or the hardware spec -- so a workload
@@ -1082,7 +1082,7 @@ def decompose(op: CollectiveOp, algorithm: str = "ring",
 # an explicit :class:`BoundedCache` (no ``lru_cache``: that would pin op
 # references for the life of the process); :func:`schedules_for_ops`
 # dedupes an op stream before decomposing and fans the shared schedule
-# objects back out, which downstream edge/phase caches key on ``id()``.
+# objects back out, which downstream edge and timing memos key on ``id()``.
 # ----------------------------------------------------------------------------
 class BoundedCache:
     """Tiny explicit LRU: ``get`` refreshes recency, ``put`` evicts the
@@ -1273,7 +1273,7 @@ def schedules_for_ops(ops: Iterable[CollectiveOp], algorithm: str,
                       ) -> list[CollectiveSchedule]:
     """Schedules for an op stream, deduped by :func:`op_signature` before
     decomposing and fanned back out: ops sharing a signature share ONE
-    schedule object, which edge/phase caches downstream key on ``id()``.
+    schedule object, which edge and timing memos downstream key on ``id()``.
     A per-call dedupe map backs the bounded cache so even a thrashing
     cache cannot force duplicate work within one stream.  The cache
     lookup is inlined (rather than delegated to :func:`cached_decompose`)
@@ -1310,167 +1310,3 @@ def schedules_for_ops(ops: Iterable[CollectiveOp], algorithm: str,
             spilled = spilled or len(local) >= cache.maxsize
         out.append(sched)
     return out
-
-
-class ScheduleBatch:
-    """Columnar view over one op stream's schedules.
-
-    Flat float64/bool/intp arrays across ALL phases of all ops --
-    ``op_index`` / ``stream`` / ``is_dcn`` / ``max_bytes`` / ``hops``
-    laid out op-major in schedule order, with ``op_phase_ptr`` (CSR-style,
-    ``nops + 1``) delimiting each op's slice -- so timing and billing run
-    as array expressions instead of per-phase Python.  ``schedules``
-    holds the (deduped, shared) schedule objects aligned with ``ops``;
-    ``edge_cache`` is the per-batch ``id(schedule) -> edge arrays`` memo
-    ``comm_matrix`` fills, so the matrix build also pays per *distinct*
-    schedule.  Every derived quantity is BITWISE identical to the per-op
-    path: phase seconds use the same scalar expression elementwise,
-    per-(op, stream, tier) sums run through unbuffered ``np.add.at`` in
-    phase order (the exact float-addition sequence of the Python loop),
-    and weighted totals reduce through a sequential Python sum.
-    """
-
-    __slots__ = ("ops", "algorithm", "topo", "schedules", "weight",
-                 "op_index", "stream", "is_dcn", "max_bytes", "hops",
-                 "op_phase_ptr", "edge_cache")
-
-    def __init__(self, ops, schedules, algorithm: Optional[str] = None,
-                 topo: Optional[MeshTopology] = None):
-        self.ops = list(ops)
-        self.schedules = list(schedules)
-        if len(self.ops) != len(self.schedules):
-            raise ValueError(
-                f"{len(self.ops)} ops vs {len(self.schedules)} schedules")
-        self.algorithm = algorithm
-        self.topo = topo
-        self.weight = np.asarray(
-            [max(1.0, float(getattr(op, "weight", 1.0)))
-             for op in self.ops], dtype=np.float64)
-        self.edge_cache: dict = {}
-        cols: dict = {}          # id(sched) -> per-phase column template
-        op_idx, streams, dcn, mb, hops = [], [], [], [], []
-        ptr = [0]
-        total = 0
-        for i, sched in enumerate(self.schedules):
-            tmpl = cols.get(id(sched))
-            if tmpl is None:
-                k = len(sched.phases)
-                tmpl = cols[id(sched)] = (
-                    np.fromiter((ph.stream for ph in sched.phases),
-                                dtype=np.intp, count=k),
-                    np.fromiter((ph.tier == "dcn" for ph in sched.phases),
-                                dtype=bool, count=k),
-                    np.fromiter((ph.max_bytes_per_rank()
-                                 for ph in sched.phases),
-                                dtype=np.float64, count=k),
-                    np.fromiter((ph.latency_hops for ph in sched.phases),
-                                dtype=np.float64, count=k),
-                )
-            k = tmpl[0].size
-            op_idx.append(np.full(k, i, dtype=np.intp))
-            streams.append(tmpl[0])
-            dcn.append(tmpl[1])
-            mb.append(tmpl[2])
-            hops.append(tmpl[3])
-            total += k
-            ptr.append(total)
-        if total:
-            self.op_index = np.concatenate(op_idx)
-            self.stream = np.concatenate(streams)
-            self.is_dcn = np.concatenate(dcn)
-            self.max_bytes = np.concatenate(mb)
-            self.hops = np.concatenate(hops)
-        else:
-            self.op_index = np.empty(0, dtype=np.intp)
-            self.stream = np.empty(0, dtype=np.intp)
-            self.is_dcn = np.empty(0, dtype=bool)
-            self.max_bytes = np.empty(0, dtype=np.float64)
-            self.hops = np.empty(0, dtype=np.float64)
-        self.op_phase_ptr = np.asarray(ptr, dtype=np.intp)
-
-    @classmethod
-    def from_ops(cls, ops, algorithm: str,
-                 topo: Optional[MeshTopology] = None, *,
-                 warn: bool = False,
-                 cache: Optional[BoundedCache] = None) -> "ScheduleBatch":
-        ops = list(ops)
-        scheds = schedules_for_ops(ops, algorithm, topo, warn=warn,
-                                   cache=cache)
-        return cls(ops, scheds, algorithm, topo)
-
-    def __len__(self) -> int:
-        return len(self.ops)
-
-    @property
-    def num_phases(self) -> int:
-        return int(self.op_index.size)
-
-    @property
-    def num_distinct(self) -> int:
-        """Distinct schedule objects (the work actually decomposed)."""
-        return len({id(s) for s in self.schedules})
-
-    def phase_slice(self, i: int) -> slice:
-        """Column slice of op ``i``'s phases (aligned with
-        ``self.schedules[i].phases``)."""
-        return slice(int(self.op_phase_ptr[i]), int(self.op_phase_ptr[i + 1]))
-
-    def phase_seconds(self, topo: Optional[MeshTopology] = None, *,
-                      include_latency: bool = True) -> np.ndarray:
-        """Per-phase streaming seconds, columnar: elementwise the exact
-        scalar expression of :meth:`CommPhase.seconds`."""
-        topo = self.topo if topo is None else topo
-        if topo is None:
-            raise ValueError("phase_seconds needs a topology")
-        bw = np.where(self.is_dcn, topo.ring_bw_per_chip(True),
-                      topo.ring_bw_per_chip(False))
-        sec = self.max_bytes / bw
-        if include_latency:
-            lat = np.where(self.is_dcn, topo.hw.dcn_hop_latency_s,
-                           topo.hw.ici_hop_latency_s)
-            sec = sec + self.hops * lat
-        return sec
-
-    def time_split_per_op(self, topo: Optional[MeshTopology] = None, *,
-                          include_latency: bool = True
-                          ) -> tuple[np.ndarray, np.ndarray]:
-        """``(ici, dcn)`` seconds per op for ONE execution -- the columnar
-        :meth:`CollectiveSchedule.time_split`: phases of one stream sum
-        (sequentially, in phase order), tiers take the max over streams."""
-        nops = len(self.ops)
-        ici = np.zeros(nops, dtype=np.float64)
-        dcn = np.zeros(nops, dtype=np.float64)
-        if self.op_index.size == 0:
-            return ici, dcn
-        sec = self.phase_seconds(topo, include_latency=include_latency)
-        # compact (op, stream) ids; streams are per-op counters < 2**31
-        pair = (self.op_index.astype(np.int64) << 31) \
-            | self.stream.astype(np.int64)
-        uniq, inv = np.unique(pair, return_inverse=True)
-        acc = np.zeros((uniq.size, 2), dtype=np.float64)
-        # np.add.at is unbuffered: within each (op, stream, tier) cell the
-        # additions land in array order == phase order, reproducing the
-        # per-op Python accumulation bitwise
-        np.add.at(acc, (inv, self.is_dcn.astype(np.intp)), sec)
-        op_of = (uniq >> 31).astype(np.intp)
-        np.maximum.at(ici, op_of, acc[:, 0])
-        np.maximum.at(dcn, op_of, acc[:, 1])
-        return ici, dcn
-
-    def total_time_split(self, topo: Optional[MeshTopology] = None, *,
-                         include_latency: bool = True
-                         ) -> tuple[float, float]:
-        """Weighted ``(ici, dcn)`` totals over the stream.  The final
-        reduction is a sequential Python sum in op order -- numpy's
-        pairwise ``sum`` is faster but not bitwise-equal to the per-op
-        accumulation loop this replaces."""
-        ici_arr, dcn_arr = self.time_split_per_op(
-            topo, include_latency=include_latency)
-        iw = ici_arr * self.weight
-        dw = dcn_arr * self.weight
-        ici = 0.0
-        dcn = 0.0
-        for a, b in zip(iw.tolist(), dw.tolist()):
-            ici += a
-            dcn += b
-        return ici, dcn

@@ -93,30 +93,17 @@ def _report_meta(report) -> dict:
     return meta
 
 
-def _memoized_schedules(report, algorithm: str) -> tuple[dict, dict]:
-    """``({id(op): CollectiveSchedule}, {id(op): phase seconds})`` from
-    the report view's memoized :class:`~repro.core.decompose.
-    ScheduleBatch` when the report offers one (a ``CommReport``), so the
-    exporter shares the IR other artifacts already computed -- including
-    the batch's columnar per-phase seconds, sliced per op -- instead of
-    re-running ``decompose`` and per-phase timing per op.  Empty dicts
-    for plain objects."""
+def _memoized_schedules(report, algorithm: str) -> dict:
+    """``{id(op): CollectiveSchedule}`` from the report view's memoized
+    :meth:`~repro.core.views.CommView.schedules` when the report offers
+    one (a ``CommReport``), so the exporter shares the IR other artifacts
+    already computed.  Empty for plain objects, whose ops the caller
+    decomposes one by one."""
     view = getattr(report, "view", None)
     if view is None:
-        return {}, {}
-    try:
-        v = view(algorithm)
-        batch = v.schedule_batch()
-        sched_of = {id(op): sched
-                    for op, sched in zip(batch.ops, batch.schedules)}
-        secs_of = {}
-        if batch.topo is not None:
-            sec = batch.phase_seconds()
-            secs_of = {id(op): sec[batch.phase_slice(i)]
-                       for i, op in enumerate(batch.ops)}
-        return sched_of, secs_of
-    except Exception:
-        return {}, {}
+        return {}
+    v = view(algorithm)
+    return {id(op): sched for op, sched in zip(v.ops, v.schedules())}
 
 
 def _ordered_ops(report, phase_names):
@@ -195,13 +182,12 @@ def trace_events(report, *, pid: int = 1) -> list[dict]:
         # overlap within the op like ``time_split``'s max-over-streams.
         # A weighted op (while-loop body) executes ``weight`` times; its
         # phases show the aggregate as one span each.
-        sched_of, secs_of = _memoized_schedules(report, algorithm)
+        sched_of = _memoized_schedules(report, algorithm)
         cursor = {"ici": 0.0, "dcn": 0.0}
         issue = 0.0   # monotone issue clock: ops are issued in program
         for op in ops:  # order, so op k+1 never *starts* before op k does
             sched = sched_of.get(id(op)) \
                 or _decompose(op, algorithm, topo, warn=False)
-            secs = secs_of.get(id(op))
             w = max(1.0, op.weight)
             # a schedule-less op (size-1 groups) moves nothing: marker at
             # the issue clock, gating nothing (no pipeline barrier)
@@ -214,10 +200,8 @@ def trace_events(report, *, pid: int = 1) -> list[dict]:
             op_end = 0.0
             stream_end: dict[int, float] = {}
             tier_events: list[dict] = []
-            for j, ph in enumerate(sched.phases):
-                sec = float(secs[j]) if secs is not None \
-                    else ph.seconds(topo)
-                dur = max(_MIN_DUR_US, sec * 1e6 * w)
+            for ph in sched.phases:
+                dur = max(_MIN_DUR_US, ph.seconds(topo) * 1e6 * w)
                 start = max(stream_end.get(ph.stream, 0.0), base[ph.tier])
                 end = start + dur
                 cursor[ph.tier] = max(cursor[ph.tier], end)

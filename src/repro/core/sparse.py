@@ -18,10 +18,12 @@ dense equivalent), link projection via :meth:`device_entries` -- and
 converts exactly via :meth:`to_dense` for small meshes and tests.
 
 :class:`SparseAccumulator` is the bounded-memory builder behind
-``comm_matrix.matrix_for_ops(..., sparse=True)``: it buffers raw COO
-chunks and coalesces (sort + reduce on encoded keys) whenever the pending
-entry count crosses a threshold, so a long op stream costs
-O(nnz + threshold) transient memory regardless of device count.
+``comm_matrix.matrix_for_schedules(..., sparse=True)`` above
+``SPARSE_DEVICE_THRESHOLD`` devices (at or below it the dense build is
+converted exactly by :func:`from_dense`): it buffers raw COO chunks and
+coalesces (sort + reduce on encoded keys) whenever the pending entry count
+crosses a threshold, so a long op stream costs O(nnz + threshold)
+transient memory regardless of device count.
 
 ``SPARSE_DEVICE_THRESHOLD`` is the auto-cutover used by
 :class:`~repro.core.views.CommView`: at or below it views build dense
@@ -31,7 +33,7 @@ still reasonable.
 """
 from __future__ import annotations
 
-from typing import Iterable, Optional
+from typing import Optional
 
 import numpy as np
 
@@ -42,36 +44,16 @@ SPARSE_DEVICE_THRESHOLD = 2048
 # raw (uncoalesced) entries buffered before an intermediate coalesce
 _COALESCE_AT = 1 << 20
 
-# counting-sort coalesce is used while side^2 float64 scratch stays modest
-# (side = SPARSE_DEVICE_THRESHOLD + 1 -> ~34 MB); the argsort path takes
-# over beyond that, preserving the O(nnz)-memory fleet guarantee
-_COUNTING_MAX_SIDE = SPARSE_DEVICE_THRESHOLD + 1
-
 
 def _coalesce(side: int, src: np.ndarray, dst: np.ndarray,
               val: np.ndarray) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Group by (src, dst) and sum duplicates.  Encoded int64 keys: safe up
-    to side ~ 3e9, far beyond any fleet.
-
-    Two strategies, identical results: a counting sort via ``np.bincount``
-    over the dense key space when ``side`` is modest (it dominated the
-    sparse-vs-dense gap: a stable ``argsort`` over millions of edges is
-    ~3x the cost of summing them), and the stable argsort + ``reduceat``
-    beyond, where ``side^2`` scratch would defeat the point of sparse.
-    Both accumulate each cell's contributions sequentially in array order,
-    so dense/sparse bitwise equality holds on either path.  The counting
-    path drops cells that sum to exactly 0.0 -- values here are
-    non-negative bytes, so such a cell only ever held zero-byte edges,
-    which no derived quantity reads.
-    """
+    """Group by (src, dst) and sum duplicates: a stable argsort on
+    encoded int64 keys (safe up to side ~ 3e9, far beyond any fleet), then
+    one ``reduceat`` per run of equal keys.  Memory stays O(nnz)."""
     if src.size == 0:
         return (np.empty(0, dtype=np.int64), np.empty(0, dtype=np.int64),
                 np.empty(0, dtype=np.float64))
     key = src.astype(np.int64) * np.int64(side) + dst.astype(np.int64)
-    if side <= _COUNTING_MAX_SIDE and key.size >= side:
-        flat = np.bincount(key, weights=val, minlength=side * side)
-        uk = np.flatnonzero(flat)
-        return uk // side, uk % side, flat[uk]
     order = np.argsort(key, kind="stable")
     key = key[order]
     val = val[order]
@@ -224,16 +206,6 @@ class SparseAccumulator:
         self.num_devices = int(num_devices)
         self.side = self.num_devices + 1
         self.coalesce_at = int(coalesce_at)
-        # At modest device counts a flat side^2 float64 working array --
-        # the dense builder's exact footprint and regime (the dense matrix
-        # is affordable here by definition) -- accumulates via ``np.add.at``
-        # on linearized keys: the same per-cell addition sequence as the
-        # dense path, so bitwise equality is free, and no concatenate /
-        # sort / bincount pass ever runs.  Beyond ``_COUNTING_MAX_SIDE``
-        # the buffered-COO path below keeps memory O(nnz + coalesce_at).
-        self._flat: Optional[np.ndarray] = (
-            None if self.side > _COUNTING_MAX_SIDE else
-            np.zeros(self.side * self.side, dtype=np.float64))
         self._src: list[np.ndarray] = []
         self._dst: list[np.ndarray] = []
         self._val: list[np.ndarray] = []
@@ -241,11 +213,6 @@ class SparseAccumulator:
 
     def add(self, src: np.ndarray, dst: np.ndarray, val: np.ndarray):
         if src.size == 0:
-            return
-        if self._flat is not None:
-            key = (np.asarray(src, dtype=np.int64) * np.int64(self.side)
-                   + np.asarray(dst, dtype=np.int64))
-            np.add.at(self._flat, key, np.asarray(val, dtype=np.float64))
             return
         self._src.append(np.asarray(src, dtype=np.int64))
         self._dst.append(np.asarray(dst, dtype=np.int64))
@@ -263,14 +230,6 @@ class SparseAccumulator:
         self._pending = src.size
 
     def build(self) -> SparseCommMatrix:
-        if self._flat is not None:
-            # exact-0.0 cells drop here, same as the counting coalesce:
-            # values are non-negative bytes, so such a cell only ever held
-            # zero-byte edges, which no derived quantity reads
-            uk = np.flatnonzero(self._flat)
-            return SparseCommMatrix(self.num_devices, uk // self.side,
-                                    uk % self.side, self._flat[uk],
-                                    coalesced=True)
         if not self._src:
             return SparseCommMatrix(self.num_devices)
         self._squash()

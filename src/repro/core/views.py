@@ -21,10 +21,8 @@ from __future__ import annotations
 
 from typing import Iterable, Optional
 
-import numpy as np
-
 from . import comm_matrix, cost_models, hlo_parser, spans
-from .decompose import ScheduleBatch
+from .decompose import schedules_for_ops
 from .events import CollectiveOp, HostTransfer
 from .sparse import SPARSE_DEVICE_THRESHOLD
 from .topology import MeshTopology
@@ -89,6 +87,9 @@ class CommView:
         # cutover -- the dense array is O(d^2) memory)
         self.sparse = sparse
         self._memo: dict = {}
+        # id(schedule) -> its edge arrays, shared by the matrix build and
+        # every per-primitive slice (schedules() keeps the keys alive)
+        self._edges: dict = {}
 
     @property
     def use_sparse(self) -> bool:
@@ -129,11 +130,10 @@ class CommView:
         """
         def build():
             with spans.span("view.matrix"):
-                batch = self.schedule_batch()
                 mat = comm_matrix.matrix_for_schedules(
-                    self.ops, batch, self.num_devices,
-                    sparse=self.use_sparse)
-                self._count_edges(batch)
+                    self.ops, self.schedules(), self.num_devices,
+                    sparse=self.use_sparse, edge_cache=self._edges)
+                self._count_edges()
                 if self.host_transfers:
                     comm_matrix.add_host_transfers(mat, self.host_transfers)
             return mat
@@ -144,21 +144,21 @@ class CommView:
         """Paper Fig. 3: one matrix per collective primitive."""
         def build():
             with spans.span("view.per_primitive"):
-                batch = self.schedule_batch()
+                scheds = self.schedules()
                 out = {k: comm_matrix.matrix_for_schedules(
-                           self.ops, batch, self.num_devices, kinds={k},
-                           sparse=self.use_sparse)
+                           self.ops, scheds, self.num_devices, kinds={k},
+                           sparse=self.use_sparse, edge_cache=self._edges)
                        for k in sorted({op.kind for op in self.ops})}
-                self._count_edges(batch)
+                self._count_edges()
                 return out
         return self._cached("per_primitive", build)
 
-    def _count_edges(self, batch: ScheduleBatch) -> None:
+    def _count_edges(self) -> None:
         """Counter ``view.edges`` of the open span: the edges the matrix
         build just placed, one per (source, destination) entry of each
-        op's schedule."""
+        op's schedule, repeats included."""
         spans.count("view.edges", sum(
-            batch.edge_cache[id(s)][0].size for s in batch.schedules))
+            self._edges[id(s)][0].size for s in self.schedules()))
 
     @property
     def summary(self) -> dict:
@@ -173,28 +173,20 @@ class CommView:
                                         topo=self.topo)))
 
     # -- decomposition schedules -------------------------------------------
-    def schedule_batch(self) -> ScheduleBatch:
-        """The columnar :class:`~repro.core.decompose.ScheduleBatch` over
-        this binding's ops -- deduped by op signature (``decompose`` runs
-        once per *distinct shape*, not once per op), memoized, and shared
-        by every derived artifact: :attr:`matrix` / :attr:`per_primitive`
-        reuse its per-schedule edge cache, the time models read its flat
-        phase columns, the Perfetto exporter slices its per-op phase
-        seconds.  Built with fallback warnings on, like the placement
-        always warned."""
-        def build():
-            with spans.span("view.schedule"):
-                batch = ScheduleBatch.from_ops(self.ops, self.algorithm,
-                                               self.topo, warn=True)
-                spans.count("view.shapes", batch.num_distinct)
-                return batch
-        return self._cached("schedule_batch", build)
-
     def schedules(self) -> list:
         """One :class:`~repro.core.decompose.CollectiveSchedule` per op
-        (aligned with ``self.ops``; ops sharing a signature share one
-        schedule object) -- the phase IR every derived artifact reads."""
-        return self.schedule_batch().schedules
+        (aligned with ``self.ops``) -- the phase IR every derived artifact
+        reads.  Deduped by op signature (``decompose`` runs once per
+        *distinct shape*, and ops sharing a signature share one schedule
+        object), memoized, and built with fallback warnings on, like the
+        placement always warned."""
+        def build():
+            with spans.span("view.schedule"):
+                scheds = schedules_for_ops(self.ops, self.algorithm,
+                                           self.topo, warn=True)
+                spans.count("view.shapes", len({id(s) for s in scheds}))
+                return scheds
+        return self._cached("schedules", build)
 
     def schedule_summaries(self) -> list[dict]:
         """Serializable per-op schedule summaries (schema-v5 section)."""
@@ -208,16 +200,23 @@ class CommView:
 
     def collective_seconds_split(self) -> tuple[float, float]:
         """Per-tier serialized collective time ``(ici_s, dcn_s)``,
-        execution-weighted, summed over the memoized schedules."""
+        execution-weighted, summed in op order."""
         def build():
             if self.topo is None:
                 return 0.0, 0.0
-            return self.schedule_batch().total_time_split(self.topo)
+            return cost_models.weighted_time_split(self.ops,
+                                                   self._time_splits())
         return self._cached("seconds_split", build)
 
     def collective_overlap_seconds(self) -> float:
         """Tier-overlapped communication time: ``max(ici_s, dcn_s)``."""
         return max(self.collective_seconds_split())
+
+    def _time_splits(self) -> list:
+        """``(ici_s, dcn_s)`` of one execution per op, each distinct
+        schedule timed once."""
+        return self._cached("time_splits", lambda: cost_models.time_splits(
+            self.schedules(), self.topo))
 
     def op_seconds(self) -> list:
         """Modeled seconds per op (aligned with ``self.ops``): each entry
@@ -228,9 +227,8 @@ class CommView:
         def build():
             if self.topo is None:
                 return [None] * len(self.ops)
-            batch = self.schedule_batch()
-            ici, dcn = batch.time_split_per_op(self.topo)
-            return ((ici + dcn) * batch.weight).tolist()
+            return [(ici + dcn) * max(1.0, op.weight)
+                    for op, (ici, dcn) in zip(self.ops, self._time_splits())]
         return self._cached("op_seconds", build)
 
     def measured_seconds(self):

@@ -12,7 +12,9 @@ import hypothesis.strategies as st
 from hypothesis import given, settings
 
 from repro.core import comm_matrix
+from repro.core.decompose import cached_decompose
 from repro.core.events import CollectiveOp, HostTransfer, Shape
+from oracles import matrix_for_ops_reference
 
 
 def mk_op(kind, dims, groups, dtype="f32", pairs=None):
@@ -156,7 +158,7 @@ class TestVectorizedBuilder:
                 warnings.simplefilter("ignore")
                 vec = comm_matrix.matrix_for_ops(check_ops, 8, algorithm,
                                                  topo=t)
-                ref = comm_matrix.matrix_for_ops_reference(
+                ref = matrix_for_ops_reference(
                     check_ops, 8, algorithm, topo=t)
             np.testing.assert_allclose(vec, ref, rtol=1e-12)
 
@@ -164,9 +166,9 @@ class TestVectorizedBuilder:
            algorithm=st.sampled_from(["ring", "tree", "hierarchical"]))
     @settings(max_examples=30, deadline=None)
     def test_edge_arrays_match_edge_tuples(self, ops, algorithm):
-        """op_edge_arrays and op_edges render the same schedules: equal
-        aggregate traffic per (src, dst) pair (edge order and splitting
-        may differ) -- including multi-axis per-axis placements."""
+        """Each schedule phase's edge arrays, summed by source, equal the
+        phase's own per-device send bytes (``CommPhase.send_bytes``) --
+        including multi-axis per-axis placements and the pod phases."""
         import warnings
         from repro.core.topology import MeshTopology
         topo = MeshTopology(axis_names=("pod", "data", "model"),
@@ -175,29 +177,26 @@ class TestVectorizedBuilder:
             for op in ops:
                 with warnings.catch_warnings():
                     warnings.simplefilter("ignore")
-                    agg_t: dict = {}
-                    for s, d, b in comm_matrix.op_edges(op, algorithm, t):
-                        agg_t[(s, d)] = agg_t.get((s, d), 0.0) + b
-                    src, dst, val = comm_matrix.op_edge_arrays(
-                        op, algorithm, t)
-                agg_a: dict = {}
-                for s, d, b in zip(src.tolist(), dst.tolist(),
-                                   val.tolist()):
-                    agg_a[(s, d)] = agg_a.get((s, d), 0.0) + b
-                assert set(agg_t) == set(agg_a)
-                for key in agg_t:
-                    assert agg_t[key] == pytest.approx(agg_a[key])
+                    sched = cached_decompose(op, algorithm, t)
+                for ph in sched.phases:
+                    src, _, val = comm_matrix._phase_edge_arrays(ph)
+                    agg: dict = {}
+                    for s, b in zip(src.tolist(), val.tolist()):
+                        agg[s] = agg.get(s, 0.0) + b
+                    want = {d: b for d, b in ph.send_bytes().items() if b}
+                    assert set(agg) == set(want)
+                    for d in want:
+                        assert agg[d] == pytest.approx(want[d])
 
     def test_flush_batching_boundary(self):
-        """Streams larger than one flush batch accumulate identically
-        (exercises the buffered-flush and the oversized-single-op paths:
-        a 192-wide all-to-all alone exceeds ``_FLUSH_EDGES``)."""
+        """A long stream of small ops followed by one op with tens of
+        thousands of edges (a 192-wide all-to-all) accumulates like the
+        legacy per-edge loop."""
         d = 192
         big = mk_op("all-to-all", (4096,), [list(range(d))])
-        assert d * (d - 1) > comm_matrix._FLUSH_EDGES
         ops = [mk_op("all-reduce", (256,), [[0, 1, 2, 3]])] * 5000 + [big]
         vec = comm_matrix.matrix_for_ops(ops, d)
-        ref = comm_matrix.matrix_for_ops_reference(ops, d)
+        ref = matrix_for_ops_reference(ops, d)
         np.testing.assert_allclose(vec, ref)
 
 

@@ -20,6 +20,7 @@ from repro.core.decompose import (CollectiveSchedule, CommPhase, decompose,
                                   group_phases)
 from repro.core.events import CollectiveOp, Shape
 from repro.core.topology import MeshTopology
+from oracles import matrix_for_ops_reference
 
 KINDS = ("all-reduce", "all-gather", "reduce-scatter",
          "collective-broadcast", "all-to-all")
@@ -69,8 +70,7 @@ class TestScheduleEqualsLegacyOnSingleAxis:
         with warnings.catch_warnings():
             warnings.simplefilter("ignore")
             new = comm_matrix.matrix_for_ops([op], nd, algorithm, topo=topo)
-            ref = comm_matrix.matrix_for_ops_reference([op], nd, algorithm,
-                                                       topo=topo)
+            ref = matrix_for_ops_reference([op], nd, algorithm, topo=topo)
         if kind == "all-to-all" and algorithm == "hierarchical" \
                 and topo is PODS_1AXIS:
             # hierarchical a2a now decomposes (the oracle keeps the flat
@@ -173,12 +173,18 @@ if _HAVE_HYPOTHESIS:
         @settings(max_examples=60, deadline=None)
         def test_matrices_and_billing_match_legacy(self, ops, algorithm):
             for topo in (None, ONE_AXIS, PODS_1AXIS):
+                # as in test_matrix_matches_legacy: hierarchical a2a on the
+                # two-pod mesh relays through pod leaders, which the legacy
+                # oracle never modeled -- only its row sums are compared
+                same = [op for op in ops if not (
+                    op.kind == "all-to-all" and algorithm == "hierarchical"
+                    and topo is PODS_1AXIS)]
                 with warnings.catch_warnings():
                     warnings.simplefilter("ignore")
-                    new = comm_matrix.matrix_for_ops(ops, 8, algorithm,
+                    new = comm_matrix.matrix_for_ops(same, 8, algorithm,
                                                      topo=topo)
-                    ref = comm_matrix.matrix_for_ops_reference(
-                        ops, 8, algorithm, topo=topo)
+                    ref = matrix_for_ops_reference(
+                        same, 8, algorithm, topo=topo)
                     np.testing.assert_allclose(new, ref, rtol=1e-12)
                     # billing: row sums == device model x weight, per op
                     for op in ops:
@@ -216,8 +222,7 @@ class TestPerAxisDecomposition:
         transit bytes vs the legacy flattened ring."""
         op = mk_op(kind, groups=[list(range(16))])
         new = comm_matrix.matrix_for_ops([op], 16, "ring", topo=MESH_4X4)
-        ref = comm_matrix.matrix_for_ops_reference([op], 16, "ring",
-                                                   topo=MESH_4X4)
+        ref = matrix_for_ops_reference([op], 16, "ring", topo=MESH_4X4)
         assert _transit_inflation(ref, MESH_4X4) > 0, \
             "legacy flattened ring must show transit inflation on 4x4"
         assert _transit_inflation(new, MESH_4X4) < \
@@ -243,7 +248,7 @@ class TestPerAxisDecomposition:
         op = mk_op("all-reduce", groups=[list(range(8))])
         new = comm_matrix.matrix_for_ops([op], 8, "hierarchical",
                                          topo=MESH_2X2X2)
-        ref = comm_matrix.matrix_for_ops_reference(
+        ref = matrix_for_ops_reference(
             [op], 8, "hierarchical", topo=MESH_2X2X2)
         assert _transit_inflation(new, MESH_2X2X2) == pytest.approx(0.0)
         # DCN bytes (the shard exchange) are identical to the legacy split

@@ -1,9 +1,11 @@
-"""Batched schedule-evaluation engine: bitwise parity with the per-op path.
+"""Schedule evaluation: bitwise parity with the per-op oracle.
 
-The engine (signature-memoized ``cached_decompose``, deduping
-``schedules_for_ops``, columnar ``ScheduleBatch``) promises every consumer
-**bitwise-identical** artifacts -- matrices (dense and sparse), billing
-totals, per-tier timing -- while decomposing once per distinct op shape.
+The evaluation path (signature-memoized ``cached_decompose``, deduping
+``schedules_for_ops``, edge arrays and ``time_split`` once per distinct
+schedule) promises every consumer **bitwise-identical** artifacts to
+decomposing and placing each op on its own -- matrices (dense and
+sparse), per-op and total per-tier timing, link projection -- while
+decomposing once per distinct op shape.
 This suite pins that promise on a deterministic grid (all op kinds x all
 algorithms x 1/2/4-pod meshes x uniform/skewed byte vectors), exercises
 the cache's correctness edges (topology/algorithm in the signature, weight
@@ -19,7 +21,7 @@ import pytest
 
 from repro.core import comm_matrix, cost_models
 from repro.core.decompose import (BoundedCache, HierarchicalFallbackWarning,
-                                  ScheduleBatch, cached_decompose,
+                                  cached_decompose,
                                   clear_schedule_cache, decompose,
                                   op_signature, reset_fallback_warnings,
                                   schedule_cache, schedules_for_ops,
@@ -27,6 +29,7 @@ from repro.core.decompose import (BoundedCache, HierarchicalFallbackWarning,
 from repro.core.cost_models import clear_billing_caches
 from repro.core.events import CollectiveOp, Shape
 from repro.core.topology import MeshTopology
+from repro.core.views import CommView
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -91,8 +94,8 @@ def make_stream(mesh_key: str, seed: int, num_ops: int = 6,
 
 
 def per_op_matrix(ops, d, alg, topo):
-    """The pre-engine oracle: decompose and place every op individually,
-    per-op ``np.add.at`` in op order (the replaced accumulation exactly)."""
+    """The per-op oracle: decompose and place every op individually,
+    per-op ``np.add.at`` in op order."""
     mat = np.zeros((d + 1, d + 1), dtype=np.float64)
     for op in ops:
         sched = decompose(op, alg, topo, warn=False)
@@ -104,13 +107,30 @@ def per_op_matrix(ops, d, alg, topo):
     return mat
 
 
+def per_op_seconds(ops, alg, topo):
+    """The per-op oracle for :meth:`CommView.op_seconds`: each op's own
+    schedule time, ``sum(time_split)`` times its execution weight."""
+    out = []
+    for op in ops:
+        ici, dcn = decompose(op, alg, topo, warn=False).time_split(topo)
+        out.append((ici + dcn) * max(1.0, op.weight))
+    return out
+
+
+def view_op_seconds(ops, d, alg, topo):
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", HierarchicalFallbackWarning)
+        return CommView(ops, d, algorithm=alg, topo=topo).op_seconds()
+
+
 GRID = [(mk, alg, skewed) for mk in MESHES for alg in ALGS
         for skewed in (False, True)]
 
 
 @pytest.mark.parametrize("mesh_key,alg,skewed", GRID)
 class TestBitwiseParity:
-    """batched == per-op, bit for bit, across the full grid."""
+    """evaluation path == per-op oracle, bit for bit, across the full
+    grid."""
 
     def _setup(self, mesh_key, alg, skewed):
         clear_schedule_cache()
@@ -139,11 +159,8 @@ class TestBitwiseParity:
 
     def test_time_split_per_op(self, mesh_key, alg, skewed):
         topo, d, ops = self._setup(mesh_key, alg, skewed)
-        batch = ScheduleBatch.from_ops(ops, alg, topo, warn=False)
-        ici, dcn = batch.time_split_per_op(topo)
-        for k, op in enumerate(ops):
-            ri, rd = decompose(op, alg, topo, warn=False).time_split(topo)
-            assert (float(ici[k]), float(dcn[k])) == (ri, rd)
+        assert view_op_seconds(ops, d, alg, topo) == per_op_seconds(
+            ops, alg, topo)
 
     def test_total_time_split(self, mesh_key, alg, skewed):
         topo, d, ops = self._setup(mesh_key, alg, skewed)
@@ -292,36 +309,6 @@ class TestSignature:
         reset_fallback_warnings()
 
 
-class TestScheduleBatchLayout:
-    def test_columns_align_with_schedules(self):
-        topo = MESHES["2pod"]
-        ops = make_stream("2pod", seed=5)
-        batch = ScheduleBatch.from_ops(ops, "ring", topo, warn=False)
-        assert len(batch) == len(ops)
-        assert batch.op_phase_ptr[0] == 0
-        assert batch.op_phase_ptr[-1] == batch.num_phases
-        for i, sched in enumerate(batch.schedules):
-            sl = batch.phase_slice(i)
-            assert sl.stop - sl.start == len(sched.phases)
-            for j, ph in enumerate(sched.phases):
-                k = sl.start + j
-                assert batch.is_dcn[k] == (ph.tier == "dcn")
-                assert batch.max_bytes[k] == ph.max_bytes_per_rank()
-                assert batch.hops[k] == ph.latency_hops
-        assert batch.num_distinct <= len(ops)
-
-    def test_phase_seconds_match_scalar_path(self):
-        topo = MESHES["4pod"]
-        ops = make_stream("4pod", seed=9, skewed=True)
-        batch = ScheduleBatch.from_ops(ops, "ring", topo, warn=False)
-        sec = batch.phase_seconds(topo)
-        k = 0
-        for sched in batch.schedules:
-            for ph in sched.phases:
-                assert float(sec[k]) == ph.seconds(topo)
-                k += 1
-
-
 if HAVE_HYPOTHESIS:
     @settings(max_examples=25, deadline=None)
     @given(mesh_key=st.sampled_from(sorted(MESHES)),
@@ -342,8 +329,5 @@ if HAVE_HYPOTHESIS:
         ref = per_op_matrix(ops, d, alg, topo)
         assert np.array_equal(got, ref)
         assert np.array_equal(sp.to_dense(), ref)
-        batch = ScheduleBatch.from_ops(ops, alg, topo, warn=False)
-        ici, dcn = batch.time_split_per_op(topo)
-        for k, op in enumerate(ops):
-            ri, rd = decompose(op, alg, topo, warn=False).time_split(topo)
-            assert (float(ici[k]), float(dcn[k])) == (ri, rd)
+        assert view_op_seconds(ops, d, alg, topo) == per_op_seconds(
+            ops, alg, topo)
